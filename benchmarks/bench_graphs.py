@@ -4,7 +4,7 @@ The paper runs the OpenMP pairwise algorithm on SNAP collaboration networks
 (ca-GrQc 5242, ca-HepPh 12008, ca-CondMat 23133) with all-pairs shortest
 path distances.  No network access here, so we synthesize collaboration-
 network-like graphs (Watts-Strogatz small worlds with planted cliques),
-compute APSP with networkx, and run the same pipeline: distances -> PaLD ->
+compute APSP on the host (scipy's csgraph BFS), and run the same pipeline: distances -> PaLD ->
 strong-tie communities, sequential vs distributed.
 """
 from __future__ import annotations
@@ -13,6 +13,7 @@ import time
 
 import networkx as nx
 import numpy as np
+from scipy.sparse.csgraph import shortest_path
 
 import jax
 
@@ -31,11 +32,10 @@ def collaboration_graph(n: int = 1024, seed: int = 0) -> np.ndarray:
         mem = rng.choice(n, size=rng.integers(5, 13), replace=False)
         G.add_edges_from((int(a), int(b)) for i, a in enumerate(mem)
                          for b in mem[i + 1:])
-    D = np.full((n, n), np.inf, np.float32)
-    for src, lengths in nx.all_pairs_shortest_path_length(G):
-        for dst, d in lengths.items():
-            D[src, dst] = d
-    np.fill_diagonal(D, 0.0)
+    # unweighted APSP: one BFS per source in C (networkx's pure-Python
+    # BFS takes minutes at the paper's n = 5,242)
+    D = shortest_path(nx.to_scipy_sparse_array(G, nodelist=range(n)),
+                      directed=False, unweighted=True).astype(np.float32)
     assert np.isfinite(D).all(), "graph must be connected"
     return D
 

@@ -41,25 +41,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from . import engine as _engine
 from .weights import index_xwins as _xwins_rows
 
-# jax.shard_map is top-level only from jax>=0.5; fall back to the
-# experimental location on older versions (this container ships 0.4.x).
-try:
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version-dependent
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-__all__ = ["pald_distributed", "pald_distributed_from_features",
-           "shard_map_compat"]
-
-
-def shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """shard_map across jax versions: new check_vma kwarg vs old check_rep."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-    except TypeError:  # pre-0.5 jax spells the kwarg check_rep
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
+__all__ = ["pald_distributed", "pald_distributed_from_features"]
 
 
 def _weights_rows(U_rows: jnp.ndarray, row_offset: jnp.ndarray, n_valid) -> jnp.ndarray:
@@ -426,7 +408,8 @@ def pald_distributed(
         raise ValueError(f"unknown strategy {strategy!r}")
 
     fn = jax.jit(
-        shard_map_compat(body, mesh=mesh, in_specs=spec_in, out_specs=out_spec)
+        jax.shard_map(body, mesh=mesh, in_specs=spec_in, out_specs=out_spec,
+                      check_vma=False)
     )
     C = fn(Dp)[:n0, :n0]
     if normalize:
@@ -519,8 +502,8 @@ def pald_distributed_from_features(
             n_valid=n_valid, plan=local_plan,
         )
     fn = jax.jit(
-        shard_map_compat(body, mesh=mesh, in_specs=P(axis_names, None),
-                         out_specs=P(axis_names, None))
+        jax.shard_map(body, mesh=mesh, in_specs=P(axis_names, None),
+                      out_specs=P(axis_names, None), check_vma=False)
     )
     C = fn(Xp)[:n0, :n0]
     if normalize:

@@ -34,11 +34,11 @@ composite (value, index) key that defines ``_top_k_rows``'s order, the
 neighbor-to-neighbor gather recomputes ``gather_tile_from_features``'s
 exact shapes, and the values stage is the shared ``knn_values_tile`` whose
 reductions run over the k axes only (per-row independent).  The one caveat
-is inherited from the selection kernel (``kernels/pald_topk.py``): tile
-distances come from a d-contraction GEMM whose summation order is
-shape-stable on TPU but on XLA:CPU only for SIMD-clean d; integer-valued
-features are exact in f32 regardless, which is what the conformance matrix
-pins (tests/test_distributed_knn.py).
+is the selection contract's (``kernels/pald_topk.py``): selection is exact
+on the distances it is given, and each shape's d-contraction GEMM sums in
+the backend's own order, so float features can differ by an ulp between
+slab shapes; integer-valued features are exact in f32 regardless, which is
+what the conformance matrix pins (tests/test_distributed_knn.py).
 
 Padded rows (n not divisible by the shard quantum) enter selection as
 masked (+inf, INT32_MAX) sentinel candidates — they lose every composite-
@@ -57,7 +57,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.tuning import autotune as _tuner
 
 from . import knn as _knn
-from .distributed import shard_map_compat
 from .features import METRICS, dist_tile
 from .resilience import fault_point, warn_once
 from .weights import DEFAULT_TIES, resolve_weight
@@ -418,9 +417,9 @@ def pald_knn_sharded(
         fault_point("distributed_knn.body", strategy=strategy, p=p,
                     mesh=tuple(mesh.devices.shape))
         spec = P(axes, None)
-        fn = jax.jit(shard_map_compat(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=spec,
-            out_specs=(spec, spec, spec)))
+            out_specs=(spec, spec, spec), check_vma=False))
         Xs = jax.device_put(Xp, NamedSharding(mesh, spec))
         dv, di, vals = fn(Xs)
         return dv[:n0], di[:n0], vals[:n0]

@@ -801,7 +801,10 @@ def plan(
     # -- tiles -------------------------------------------------------------
     block_source = "explicit"
     if block is None:
-        block = "auto" if method in ("fused", "knn") else 128
+        # Mosaic tiles depend on n (aligned z tiles, the tri slab's VMEM),
+        # so the pallas kernel pipeline resolves them like fused/knn do
+        auto = method in ("fused", "knn") or impl == "pallas"
+        block = "auto" if auto else 128
         block_source = "default"
     if method == "knn":
         if block == "auto":
@@ -849,7 +852,8 @@ def plan(
             block_source = src if was_auto else f"{block_source}; z:{src}"
     elif block == "auto" or block_z == "auto":
         pass_ = "pald_tri" if schedule == "tri" else "pald"
-        rb, rbz, src = _tuner.resolve_blocks_ex(n, pass_, ties=weight)
+        rb, rbz, src = _tuner.resolve_blocks_ex(n, pass_, ties=weight,
+                                                impl=impl)
         block_source = src if block == "auto" else f"{block_source}; z:{src}"
         block = rb if block == "auto" else block
         if method == "kernel" and block_z in (None, "auto"):

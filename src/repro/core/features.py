@@ -56,12 +56,23 @@ def dist_tile(XA: jnp.ndarray, XB: jnp.ndarray, metric: str,
               *, loop_d: bool = False) -> jnp.ndarray:
     """(ma, d) x (mb, d) -> (ma, mb) distances, float32.
 
-    ``loop_d=True`` streams the feature axis with a fori_loop instead of
+    The dot-product metrics contract the feature axis with one
+    ``dot_general`` at ``Precision.HIGHEST``: full f32 inputs and f32
+    accumulation in XLA and in a Mosaic kernel alike, so no backend may
+    round the features to bf16 first.  That makes every caller, Pallas
+    kernel or jnp slab, compute the same f32 distance up to the order of
+    the d-term sum.  The order is the backend's: two tile shapes can differ
+    by an ulp (XLA:CPU picks its GEMM blocking by shape), and features whose
+    dot products are exact in f32 (small integers, such as uint8 SIFT
+    descriptors) give bitwise-equal distances on every path.
+
+    ``loop_d=True`` walks the feature axis one column at a time instead of
     materializing the (ma, mb, d) broadcast cube — the manhattan form the
-    Pallas kernels use so VMEM stays at tile size.  Zero-padded feature
-    columns are exact no-ops for every metric (they add 0 to dots, norms
-    and absolute differences), which is what lets the kernels pad d up to
-    the TPU lane quantum.
+    Pallas kernels use so VMEM stays at tile size.  The walk is unrolled at
+    trace time (static column slices), which is what Mosaic lowers.
+    Zero-padded feature columns are exact no-ops for every metric (they add
+    0 to dots, norms and absolute differences), which is what lets the
+    kernels pad d up to the TPU lane quantum.
     """
     if metric not in METRICS:
         raise ValueError(f"unknown metric {metric!r} (expected one of {METRICS})")
@@ -72,6 +83,7 @@ def dist_tile(XA: jnp.ndarray, XB: jnp.ndarray, metric: str,
         nb = jnp.sum(XB * XB, axis=1, keepdims=True)            # (mb, 1)
         dot = jax.lax.dot_general(
             XA, XB, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         d2 = jnp.maximum(na + nb.T - 2.0 * dot, 0.0)
@@ -83,21 +95,16 @@ def dist_tile(XA: jnp.ndarray, XB: jnp.ndarray, metric: str,
                                   _NORM_EPS))
         dot = jax.lax.dot_general(
             XA, XB, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         )
         return 1.0 - dot / (na * nb.T)
     # manhattan
     if loop_d:
-        d = XA.shape[1]
-
-        def body(j, acc):
-            ca = jax.lax.dynamic_slice_in_dim(XA, j, 1, axis=1)  # (ma, 1)
-            cb = jax.lax.dynamic_slice_in_dim(XB, j, 1, axis=1)  # (mb, 1)
-            return acc + jnp.abs(ca - cb.T)
-
-        return jax.lax.fori_loop(
-            0, d, body, jnp.zeros((XA.shape[0], XB.shape[0]), jnp.float32)
-        )
+        acc = jnp.zeros((XA.shape[0], XB.shape[0]), jnp.float32)
+        for j in range(XA.shape[1]):
+            acc = acc + jnp.abs(XA[:, j:j + 1] - XB[:, j:j + 1].T)
+        return acc
     return jnp.sum(jnp.abs(XA[:, None, :] - XB[None, :, :]), axis=-1)
 
 

@@ -25,8 +25,12 @@ need the global-index x>y predicate.  Two equivalent static specs:
   row iota, so no (mx, my) tiebreak array ever materializes.  This is
   the default route for the sequential square case (offsets (0, 0)).
 
-VMEM = D_XZ + C_XZ + D_YZ + D_XY + W_XY (+ XW_XY for the explicit-XW
-route) = 3*bx*bz + 2*bx*by (+ bx*by) floats.
+The y-loop (``tile_loops.cohesion_tile``) reads D[X, Y], W (and XW)
+columns as rows of transposed (by, bx) scratch copies, filled once per
+grid step.
+
+VMEM = D_XZ + C_XZ + D_YZ + D_XY + W_XY + 2 scratch (+ XW_XY and its
+scratch for the explicit-XW route) = 3*bx*bz + 4*bx*by (+ 2*bx*by) floats.
 """
 from __future__ import annotations
 
@@ -35,37 +39,30 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.weights import DEFAULT_TIES, resolve_weight, support_weight
+from repro.core.weights import DEFAULT_TIES, resolve_weight
+
+from .tile_loops import col, cohesion_tile
 
 __all__ = ["cohesion_pallas"]
 
 
-def _cohesion_kernel(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, *, ties):
+def _cohesion_kernel(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, dyx_ref, wt_ref,
+                     *, ties):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    dxz = dxz_ref[...]  # (bx, bz)
-    dyz = dyz_ref[...]  # (by, bz)
-    dxy = dxy_ref[...]  # (bx, by)
-    w = w_ref[...]      # (bx, by)
-    by = dxy.shape[1]
-
-    def body(y, acc):
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)   # (1, bz)  d_yz
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)   # (bx, 1) d_xy
-        wy = jax.lax.dynamic_slice_in_dim(w, y, 1, axis=1)      # (bx, 1)
-        g = support_weight(dxz, row, thr, ties)                 # (bx, bz)
-        return acc + g * wy
-
-    add = jax.lax.fori_loop(0, by, body, jnp.zeros_like(c_ref))
-    c_ref[...] += add
+    dyx_ref[...] = dxy_ref[...].T       # (by, bx): row y = column y of D[X, Y]
+    wt_ref[...] = w_ref[...].T
+    c_ref[...] += cohesion_tile(dxz_ref[...], dyz_ref, dyx_ref, wt_ref, ties)
 
 
-def _cohesion_kernel_xw(dxz_ref, dyz_ref, dxy_ref, w_ref, xw_ref, c_ref, *, ties):
+def _cohesion_kernel_xw(dxz_ref, dyz_ref, dxy_ref, w_ref, xw_ref, c_ref,
+                        dyx_ref, wt_ref, xwt_ref, *, ties):
     """Index-tiebreak variant with an explicit (bx, by) tiebreak tile."""
     k = pl.program_id(2)
 
@@ -73,27 +70,15 @@ def _cohesion_kernel_xw(dxz_ref, dyz_ref, dxy_ref, w_ref, xw_ref, c_ref, *, ties
     def _init():
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    dxz = dxz_ref[...]
-    dyz = dyz_ref[...]
-    dxy = dxy_ref[...]
-    w = w_ref[...]
-    xw = xw_ref[...]    # (bx, by) 1.0 where global x index > global y index
-    by = dxy.shape[1]
-
-    def body(y, acc):
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)
-        wy = jax.lax.dynamic_slice_in_dim(w, y, 1, axis=1)
-        xwy = jax.lax.dynamic_slice_in_dim(xw, y, 1, axis=1) > 0.5  # (bx, 1)
-        g = support_weight(dxz, row, thr, ties, xwy)
-        return acc + g * wy
-
-    add = jax.lax.fori_loop(0, by, body, jnp.zeros_like(c_ref))
-    c_ref[...] += add
+    dyx_ref[...] = dxy_ref[...].T
+    wt_ref[...] = w_ref[...].T
+    xwt_ref[...] = xw_ref[...].T        # 1.0 where global x index > global y
+    c_ref[...] += cohesion_tile(dxz_ref[...], dyz_ref, dyx_ref, wt_ref, ties,
+                                lambda y: col(xwt_ref, y) > 0.5)
 
 
-def _cohesion_kernel_iota(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, *, ties,
-                          row_off, col_off, block_x, block_y):
+def _cohesion_kernel_iota(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, dyx_ref,
+                          wt_ref, *, ties, row_off, col_off, block_x, block_y):
     """Index-tiebreak variant deriving x>y per tile from grid position.
 
     Global x index of tile row r is ``row_off + i*block_x + r``; global y
@@ -107,25 +92,13 @@ def _cohesion_kernel_iota(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, *, ties,
     def _init():
         c_ref[...] = jnp.zeros_like(c_ref)
 
-    dxz = dxz_ref[...]
-    dyz = dyz_ref[...]
-    dxy = dxy_ref[...]
-    w = w_ref[...]
-    by = dxy.shape[1]
+    dyx_ref[...] = dxy_ref[...].T
+    wt_ref[...] = w_ref[...].T
     xg = row_off + i * block_x + jax.lax.broadcasted_iota(
-        jnp.int32, (dxz.shape[0], 1), 0)                        # (bx, 1)
+        jnp.int32, (block_x, 1), 0)                             # (bx, 1)
     ybase = col_off + k * block_y
-
-    def body(y, acc):
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)
-        wy = jax.lax.dynamic_slice_in_dim(w, y, 1, axis=1)
-        xwy = xg > ybase + y                                    # (bx, 1)
-        g = support_weight(dxz, row, thr, ties, xwy)
-        return acc + g * wy
-
-    add = jax.lax.fori_loop(0, by, body, jnp.zeros_like(c_ref))
-    c_ref[...] += add
+    c_ref[...] += cohesion_tile(dxz_ref[...], dyz_ref, dyx_ref, wt_ref, ties,
+                                lambda y: xg > ybase + y)
 
 
 @functools.partial(jax.jit, static_argnames=("block_x", "block_z", "block_y",
@@ -185,12 +158,15 @@ def cohesion_general_pallas(
                              "(global-index tiebreak)")
     else:
         kernel = functools.partial(_cohesion_kernel, ties=wfun)
+    # transposed (by, bx) scratch tiles: D[X, Y]^T, W^T (and XW^T)
+    n_scratch = len(in_specs) - 2
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_x, block_z), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mx, mz), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_y, block_x), jnp.float32)] * n_scratch,
         interpret=interpret,
     )(*args)
 

@@ -34,8 +34,13 @@ Accumulation layout (grid = (nz, npairs), pairs innermost, x-major order):
 * y-role → ``Cy`` (n, block_z * nz = n): output block (n, block_z) at
   (0, k) — the full column slab for the current z-chunk.  Its index map is
   constant in t, so it too is revisited only consecutively; rows ys[t] are
-  updated in place with a dynamic-slice store.  VMEM cost n * block_z
-  floats, which bounds block_z for large n (the autotuner's job).
+  updated in place with a dynamic row-range store.  VMEM cost n * block_z
+  floats (twice, double-buffered), which bounds block_z for large n (the
+  autotuner's job).
+
+Every y-indexed read in the tile loop is a row read: D[X, Y] and W[X, Y]
+are transposed into (b, b) scratch once per step, and the y-role rows are
+built in a (b, bz) scratch (``tile_loops``).
 
 Diagonal blocks (xb == yb) apply the dense one-sided x-role over the full
 (block, block) pair square — that already covers both orders of every
@@ -57,11 +62,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.weights import DEFAULT_TIES, resolve_weight, support_weight
 
+from .tile_loops import col
+
 __all__ = ["cohesion_tri_pallas"]
 
 
 def _cohesion_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, w_ref,
-                         cx_ref, cy_ref, *, ties):
+                         cx_ref, cy_ref, dyx_ref, wt_ref, ry_ref, *, ties):
     t = pl.program_id(1)
     xb = xs_ref[t]
     yb = ys_ref[t]
@@ -75,18 +82,16 @@ def _cohesion_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, w_ref,
     def _init_cy():
         cy_ref[...] = jnp.zeros_like(cy_ref)
 
-    dxz = dxz_ref[...]  # (b, bz)  D[X, z-chunk]
-    dyz = dyz_ref[...]  # (b, bz)  D[Y, z-chunk]
-    dxy = dxy_ref[...]  # (b, b)   D[X, Y]
-    w = w_ref[...]      # (b, b)   W[X, Y]
-    b = dxy.shape[1]
+    dxz = dxz_ref[...]                  # (b, bz)  D[X, z-chunk]
+    dyx_ref[...] = dxy_ref[...].T       # (b, b)   row y = column y of D[X, Y]
+    wt_ref[...] = w_ref[...].T          # (b, b)   row y = column y of W[X, Y]
+    b = dyx_ref.shape[0]
     is_diag = xb == yb
 
-    def body(y, accs):
-        acc_x, acc_y = accs
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)   # (1, bz) d_yz
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)   # (b, 1)  d_xy
-        wy = jax.lax.dynamic_slice_in_dim(w, y, 1, axis=1)      # (b, 1)
+    def body(y, acc_x):
+        row = dyz_ref[pl.ds(y, 1), :]   # (1, bz) d_yz
+        thr = col(dyx_ref, y)           # (b, 1)  d_xy
+        wy = col(wt_ref, y)             # (b, 1)
         xw = yw = None
         if ties.needs_index_tiebreak:
             # global-index tiebreak from the prefetched block coordinates; on
@@ -99,21 +104,16 @@ def _cohesion_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, w_ref,
         acc_x = acc_x + gx * wy
         # y-role: one output row, reduced over the x axis
         gy = support_weight(row, dxz, thr, ties, yw)            # (b, bz)
-        ry = jnp.sum(gy * wy, axis=0, keepdims=True)
-        acc_y = jax.lax.dynamic_update_slice_in_dim(acc_y, ry, y, axis=0)
-        return acc_x, acc_y
+        ry_ref[pl.ds(y, 1), :] = jnp.sum(gy * wy, axis=0, keepdims=True)
+        return acc_x
 
-    bx, bz = dxz.shape
-    add_x, add_y = jax.lax.fori_loop(
-        0, b, body,
-        (jnp.zeros((bx, bz), jnp.float32), jnp.zeros((b, bz), jnp.float32)),
-    )
-    cx_ref[...] += add_x
+    cx_ref[...] += jax.lax.fori_loop(0, b, body,
+                                     jnp.zeros(dxz.shape, jnp.float32))
 
     @pl.when(jnp.logical_not(is_diag))
     def _update_cy():
         start = yb * b
-        cy_ref[pl.ds(start, b), :] += add_y
+        cy_ref[pl.ds(start, b), :] += ry_ref[...]
 
 
 @functools.partial(jax.jit, static_argnames=("block", "block_z", "interpret",
@@ -160,6 +160,10 @@ def cohesion_tri_pallas(
             # y-role: whole column slab of Cy, resident across the k-th sweep
             pl.BlockSpec((n, block_z), lambda k, t, xs, ys: (0, k)),
         ],
+        # D[X, Y]^T and W[X, Y]^T for the y-loop's column reads, and the
+        # y-role rows of the current pair
+        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)] * 2
+        + [pltpu.VMEM((block, block_z), jnp.float32)],
     )
     Cx, Cy = pl.pallas_call(
         functools.partial(_cohesion_tri_kernel, ties=ties),

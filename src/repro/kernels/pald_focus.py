@@ -8,9 +8,11 @@ exactly like a blocked-matmul accumulator — the TPU analogue of the paper's
 "U_XY remains in fast memory through the pass" (Theorem 4.1 proof).
 
 Inside the kernel we iterate the y dimension with a fori_loop over rows so
-the live working set is (bx, bz) vectors instead of a (bx, by, bz) cube:
-VMEM = D_XZ + D_YZ + D_XY + U_XY = 2*bx*bz + bx*by + bx*by floats.
-With bx=by=128, bz=512 that is ~0.66 MiB, well under ~16 MiB VMEM, and all
+the live working set is (bx, bz) vectors instead of a (bx, by, bz) cube
+(``tile_loops.focus_tile``); two (by, bx) scratch tiles hold D[X, Y]^T and
+the U block being built, so every y-indexed read is a row read.
+VMEM = D_XZ + D_YZ + D_XY + U_XY + 2 scratch = 2*bx*bz + 4*bx*by floats.
+With bx=by=128, bz=512 that is 0.75 MiB, well under ~16 MiB VMEM, and all
 tile shapes are (8,128)-aligned.
 """
 from __future__ import annotations
@@ -20,34 +22,24 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.weights import DEFAULT_TIES, focus_weight, resolve_weight
+from repro.core.weights import DEFAULT_TIES, resolve_weight
+
+from .tile_loops import focus_tile
 
 __all__ = ["focus_pallas"]
 
 
-def _focus_kernel(dxz_ref, dyz_ref, dxy_ref, u_ref, *, ties):
+def _focus_kernel(dxz_ref, dyz_ref, dxy_ref, u_ref, dyx_ref, ut_ref, *, ties):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    dxz = dxz_ref[...]  # (bx, bz)
-    dyz = dyz_ref[...]  # (by, bz)
-    dxy = dxy_ref[...]  # (bx, by)
-    by = dxy.shape[1]
-
-    def body(y, acc):
-        # column y of the U block: sum_z focus_weight(d_xz, d_yz[y], d_xy[:,y])
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)      # (bx, 1)
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)      # (1, bz)
-        m = focus_weight(dxz, row, thr, ties)                      # (bx, bz)
-        col = jnp.sum(m, axis=1, keepdims=True)
-        return jax.lax.dynamic_update_slice_in_dim(acc, col, y, axis=1)
-
-    add = jax.lax.fori_loop(0, by, body, jnp.zeros_like(u_ref))
-    u_ref[...] += add
+    dyx_ref[...] = dxy_ref[...].T       # (by, bx): row y = column y of D[X, Y]
+    u_ref[...] += focus_tile(dxz_ref[...], dyz_ref, dyx_ref, ut_ref, ties)
 
 
 @functools.partial(jax.jit, static_argnames=("block_x", "block_y", "block_z",
@@ -86,6 +78,7 @@ def focus_general_pallas(
         ],
         out_specs=pl.BlockSpec((block_x, block_y), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mx, my), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_y, block_x), jnp.float32)] * 2,
         interpret=interpret,
     )(DXZ.astype(jnp.float32), DYZ.astype(jnp.float32), DXY.astype(jnp.float32))
 

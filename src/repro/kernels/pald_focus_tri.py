@@ -24,12 +24,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.weights import DEFAULT_TIES, focus_weight, resolve_weight
+from repro.core.weights import DEFAULT_TIES, resolve_weight
+
+from .tile_loops import focus_tile
 
 __all__ = ["focus_tri_pallas"]
 
 
-def _focus_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, u_ref, *, ties):
+def _focus_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, u_ref,
+                      dyx_ref, ut_ref, *, ties):
     # xs_ref/ys_ref are scalar-prefetch refs (consumed by the index maps);
     # the kernel body itself is identical to the dense focus kernel.
     del xs_ref, ys_ref
@@ -39,20 +42,8 @@ def _focus_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, u_ref, *, ties)
     def _init():
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    dxz = dxz_ref[...]  # (b, bz)  rows of the X block
-    dyz = dyz_ref[...]  # (b, bz)  rows of the Y block
-    dxy = dxy_ref[...]  # (b, b)   D[X, Y]
-    bx, b = dxy.shape
-
-    def body(y, acc):
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)      # (b, 1)
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)      # (1, bz)
-        m = focus_weight(dxz, row, thr, ties)
-        col = jnp.sum(m, axis=1, keepdims=True)
-        return jax.lax.dynamic_update_slice_in_dim(acc, col, y, axis=1)
-
-    add = jax.lax.fori_loop(0, b, body, jnp.zeros((bx, b), jnp.float32))
-    u_ref[0] += add
+    dyx_ref[...] = dxy_ref[...].T       # (b, b): row y = column y of D[X, Y]
+    u_ref[0] += focus_tile(dxz_ref[...], dyz_ref, dyx_ref, ut_ref, ties)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "block_z", "interpret",
@@ -91,6 +82,7 @@ def focus_tri_pallas(
         out_specs=pl.BlockSpec(
             (1, block, block), lambda t, k, xs, ys: (t, 0, 0)
         ),
+        scratch_shapes=[pltpu.VMEM((block, block), jnp.float32)] * 2,
     )
     packed = pl.pallas_call(
         functools.partial(_focus_tri_kernel, ties=ties),

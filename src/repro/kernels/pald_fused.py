@@ -7,7 +7,9 @@ step loads the (block, d) feature tiles it needs, computes the
 (block, block) / (block, block_z) distance tiles in VMEM via
 ``features.dist_tile`` (matmul-backed for sqeuclidean / euclidean / cosine,
 d-streamed for manhattan), and then runs the *same* focus / cohesion tile
-bodies as the dense kernels.  ``D`` never exists in HBM.
+bodies as the dense kernels (``tile_loops``), with D[Y, Z] and D[X, Y]^T
+parked in VMEM scratch so the y-loop reads rows.  ``D`` never exists in
+HBM.
 
 Grid shapes and the accumulator-residency discipline are identical to the
 dense kernels (DESIGN.md §4.1); the only new cost is recomputing distance
@@ -27,16 +29,19 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.features import masked_dist_tile
-from repro.core.weights import (DEFAULT_TIES, focus_weight, resolve_weight,
-                                support_weight)
+from repro.core.weights import DEFAULT_TIES, resolve_weight
+
+from .tile_loops import cohesion_tile, focus_tile
 
 __all__ = ["focus_fused_pallas", "cohesion_fused_pallas"]
 
 
-def _focus_fused_kernel(xi_ref, xj_ref, xk_ref, u_ref, *, metric, n_valid,
-                        block, block_y, block_z, ties):
+def _focus_fused_kernel(xi_ref, xj_ref, xk_ref, u_ref, dyz_ref, dyx_ref,
+                        ut_ref, *, metric, n_valid, block, block_y, block_z,
+                        ties):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -48,22 +53,14 @@ def _focus_fused_kernel(xi_ref, xj_ref, xk_ref, u_ref, *, metric, n_valid,
     zoff = k * block_z
     dxz = masked_dist_tile(xi_ref[...], xk_ref[...], metric, xoff, zoff,
                            n_valid, loop_d=True)   # (bx, bz)
-    dyz = masked_dist_tile(xj_ref[...], xk_ref[...], metric, yoff, zoff,
-                           n_valid, loop_d=True)   # (by, bz)
+    dyz_ref[...] = masked_dist_tile(xj_ref[...], xk_ref[...], metric, yoff,
+                                    zoff, n_valid, loop_d=True)   # (by, bz)
     dxy = masked_dist_tile(xi_ref[...], xj_ref[...], metric, xoff, yoff,
                            n_valid, loop_d=True)   # (bx, by)
-    by = dxy.shape[1]
+    dyx_ref[...] = dxy.T
 
-    # identical tile body to pald_focus._focus_kernel
-    def body(y, acc):
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)      # (bx, 1)
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)      # (1, bz)
-        m = focus_weight(dxz, row, thr, ties)
-        col = jnp.sum(m, axis=1, keepdims=True)
-        return jax.lax.dynamic_update_slice_in_dim(acc, col, y, axis=1)
-
-    add = jax.lax.fori_loop(0, by, body, jnp.zeros_like(u_ref))
-    u_ref[...] += add
+    # the tile body of pald_focus._focus_kernel
+    u_ref[...] += focus_tile(dxz, dyz_ref, dyx_ref, ut_ref, ties)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -99,12 +96,15 @@ def focus_fused_pallas(
         ],
         out_specs=pl.BlockSpec((block, block_y), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_y, block_z), jnp.float32)]
+        + [pltpu.VMEM((block_y, block), jnp.float32)] * 2,
         interpret=interpret,
     )(X.astype(jnp.float32), X.astype(jnp.float32), X.astype(jnp.float32))
 
 
-def _cohesion_fused_kernel(xi_ref, xj_ref, xk_ref, w_ref, c_ref, *, metric,
-                           n_valid, block, block_y, block_z, ties):
+def _cohesion_fused_kernel(xi_ref, xj_ref, xk_ref, w_ref, c_ref, dyz_ref,
+                           dyx_ref, wt_ref, *, metric, n_valid, block,
+                           block_y, block_z, ties):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -116,27 +116,20 @@ def _cohesion_fused_kernel(xi_ref, xj_ref, xk_ref, w_ref, c_ref, *, metric,
     yoff = k * block_y
     dxz = masked_dist_tile(xi_ref[...], xj_ref[...], metric, xoff, zoff,
                            n_valid, loop_d=True)   # (bx, bz)
-    dyz = masked_dist_tile(xk_ref[...], xj_ref[...], metric, yoff, zoff,
-                           n_valid, loop_d=True)   # (by, bz)
+    dyz_ref[...] = masked_dist_tile(xk_ref[...], xj_ref[...], metric, yoff,
+                                    zoff, n_valid, loop_d=True)   # (by, bz)
     dxy = masked_dist_tile(xi_ref[...], xk_ref[...], metric, xoff, yoff,
                            n_valid, loop_d=True)   # (bx, by)
-    w = w_ref[...]                                 # (bx, by)
-    by = dxy.shape[1]
-    bx = dxz.shape[0]
-    xg = xoff + jax.lax.broadcasted_iota(jnp.int32, (bx, 1), 0)
+    dyx_ref[...] = dxy.T
+    wt_ref[...] = w_ref[...].T
+    own_wins = None
+    if ties.needs_index_tiebreak:
+        # the grid owns both offsets, so the index tiebreak is an iota
+        xg = xoff + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        own_wins = lambda y: xg > yoff + y  # noqa: E731
 
-    # identical tile body to pald_cohesion._cohesion_kernel; the grid owns
-    # both offsets, so the index tiebreak is an in-kernel iota
-    def body(y, acc):
-        row = jax.lax.dynamic_slice_in_dim(dyz, y, 1, axis=0)   # (1, bz)
-        thr = jax.lax.dynamic_slice_in_dim(dxy, y, 1, axis=1)   # (bx, 1)
-        wy = jax.lax.dynamic_slice_in_dim(w, y, 1, axis=1)      # (bx, 1)
-        xw = (xg > yoff + y) if ties.needs_index_tiebreak else None
-        g = support_weight(dxz, row, thr, ties, xw)
-        return acc + g * wy
-
-    add = jax.lax.fori_loop(0, by, body, jnp.zeros_like(c_ref))
-    c_ref[...] += add
+    # the tile body of pald_cohesion._cohesion_kernel
+    c_ref[...] += cohesion_tile(dxz, dyz_ref, dyx_ref, wt_ref, ties, own_wins)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -175,6 +168,8 @@ def cohesion_fused_pallas(
         ],
         out_specs=pl.BlockSpec((block, block_z), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, m), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((block_y, block_z), jnp.float32)]
+        + [pltpu.VMEM((block_y, block), jnp.float32)] * 2,
         interpret=interpret,
     )(X.astype(jnp.float32), X.astype(jnp.float32), X.astype(jnp.float32),
       W.astype(jnp.float32))

@@ -7,39 +7,44 @@ row.  This kernel is the streaming form of the same contract: the grid walks
 (block, d) x (block_z, d) feature tile pairs (the dataflow of
 ``kernels/pald_fused.py``), computes each (block, block_z) distance tile
 in-register via ``features.dist_tile``, and folds it into a running
-(block, kp) best-list held in the output ref — so neither D nor any full
+(block, out_w) best-list held in the output ref — so neither D nor any full
 per-row score vector ever exists in HBM.
 
 Selection network
 -----------------
-Each tile is sorted with a bitonic network over COMPOSITE (value, index)
-keys — compare-exchange swaps on ``(v1 > v2) | ((v1 == v2) & (i1 > i2))`` —
-then its kp best columns are merged into the incumbent best-list with a
-single bitonic merge of the 2*kp concatenation (incumbent ascending ++
-candidates descending is bitonic by construction).  Because every real
-candidate has a distinct global column index, the composite key is a total
-order, which makes the maintained list exactly the first kp entries of the
-stable ``lax.top_k`` order on negated distances — the lower-index-first
-tie-break of ``core.knn._top_k_rows`` — independent of the tile visit
-order.
+Each tile is sorted descending with a bitonic network over COMPOSITE
+(value, index) keys — compare-exchange swaps on
+``(v1 > v2) | ((v1 == v2) & (i1 > i2))`` — so its best entries end the
+tile.  The last ``out_w`` lanes follow the ascending incumbent best-list to
+form a bitonic 2*out_w sequence, and one bitonic merge keeps the out_w best
+(``fold_tile``).  Partners are found by lane rotation (``pltpu.roll``), the
+form Mosaic lowers; the network never reverses or reshapes the lane axis.
+Because every real candidate has a distinct global column index, the
+composite key is a total order, which makes the maintained list exactly the
+first out_w entries of the stable ``lax.top_k`` order on negated distances —
+the lower-index-first tie-break of ``core.knn._top_k_rows`` — independent
+of the tile visit order.
 
 Masking contract: the self column and every padded row/column (global index
 >= ``n_valid``) enter the network as (+inf, INT32_MAX) and therefore lose
 to every real candidate; with k <= n-1 real candidates per row they can
 never reach the returned k columns of a real row.
 
-TPU alignment: ``kp`` (k rounded up to a power of two, the network width)
-is lane-padded to 128 for the output refs off interpret mode; the caller
-slices back to k.  ``block_z`` must be a power of two >= kp.
+TPU alignment: the best-list is ``out_w = max(kp, 128)`` lanes wide (kp is
+k rounded up to a power of two); the caller slices back to k.
+``block_z`` must be a power of two >= kp.
 
-Bitwise scope: the selection machinery above is exact — given tile
-distance values it reproduces ``_top_k_rows`` bit-for-bit.  The tile
-distances themselves come from ``dist_tile``'s GEMM, whose per-pair
-contraction order is fixed by d alone on the TPU MXU but is only
-shape-stable on XLA:CPU for SIMD-clean d (e.g. 4, 8); for ragged d the
-(block, block_z) tile GEMM can differ from the jnp slab GEMM by 1 ulp.
-That is an XLA:CPU property shared by every tiled kernel in this repo
-(see tests/test_topk_conformance.py), not a property of this network.
+Contract: selection is exact on the distance values it is given — the
+network reproduces ``_top_k_rows`` on the same values bit for bit
+(tests/test_topk_conformance.py feeds it identical tiles).  The distances
+come from ``dist_tile``, whose dot products run at
+``Precision.HIGHEST`` (f32 in, f32 accumulate) on every path, so the kernel
+and the jnp slab paths compute the same f32 distance up to the order of the
+d-term sum.  That order belongs to the backend (XLA:CPU picks its GEMM
+blocking per shape, so a (block, block_z) tile and a (chunk, n) slab can
+differ by an ulp); where every dot product is exact in f32 — small integer
+features such as uint8 descriptors — the distances, and hence the selected
+neighbors, are bitwise equal on every path.
 """
 from __future__ import annotations
 
@@ -50,10 +55,12 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.features import dist_tile
 
-__all__ = ["topk_pallas", "sort_pairs", "merge_pairs", "next_pow2"]
+__all__ = ["topk_pallas", "fold_tile", "sort_pairs", "merge_pairs",
+           "next_pow2"]
 
 _LANE = 128
 _IDX_PAD = np.iinfo(np.int32).max
@@ -73,39 +80,44 @@ def _pairs_gt(v1, i1, v2, i2):
     return (v1 > v2) | ((v1 == v2) & (i1 > i2))
 
 
-def _cx_pass(v, i, j: int, k: int | None):
+def _partner(x, lane, j: int):
+    """``x`` at lane ``lane ^ j``, for every lane of a (b, w) tile.
+
+    Two lane rotations by +-j hold the partner on one side or the other;
+    rotating the lane iota the same way says which, so the result does not
+    depend on the rotation's direction convention.  Mosaic lowers a lane
+    rotation natively, where a lane reshape or reversal is refused."""
+    w = x.shape[-1]
+    src = pltpu.roll(lane, j, 1)
+    return jnp.where(src == (lane ^ j), pltpu.roll(x, j, 1),
+                     pltpu.roll(x, w - j, 1))
+
+
+def _cx_pass(v, i, j: int, k: int | None, descending: bool = False):
     """One compare-exchange pass at stride ``j`` over the last axis.
 
-    ``k`` is the bitonic sort stage (direction alternates per k-aligned
-    run, ascending first); ``k=None`` is the all-ascending merge form.
-    The pairing trick: reshape (b, w) -> (b, w/(2j), 2, j) puts partners
-    (idx, idx^j) on axis 2, and since 2j divides k the direction bit
-    (idx & k) is constant per reshaped row — a static mask, no gathers.
-    """
-    b, w = v.shape
-    q = w // (2 * j)
-    v4 = v.reshape(b, q, 2, j)
-    i4 = i.reshape(b, q, 2, j)
-    lo_v, hi_v = v4[:, :, 0, :], v4[:, :, 1, :]
-    lo_i, hi_i = i4[:, :, 0, :], i4[:, :, 1, :]
-    swap = _pairs_gt(lo_v, lo_i, hi_v, hi_i)
+    Lane l pairs with lane l ^ j; the lower lane of a pair keeps the
+    smaller composite key when the pair sorts ascending.  ``k`` is the
+    bitonic sort stage (direction alternates per k-aligned run, ascending
+    first, or descending first with ``descending``); ``k=None`` is the
+    all-ascending merge form.  Each pair's swap decision is taken from its
+    lower lane's point of view, so both lanes agree even on keys that
+    compare unordered."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+    pv, pi = _partner(v, lane, j), _partner(i, lane, j)
+    # boolean algebra, not a select: Mosaic lowers no select of i1 vectors
+    is_lo = (lane & j) == 0
+    swap = ((is_lo & _pairs_gt(v, i, pv, pi))
+            | (~is_lo & _pairs_gt(pv, pi, v, i)))
     if k is not None:
-        # direction bit from an in-kernel iota (a host-side numpy mask
-        # would be a captured constant, which pallas_call rejects)
-        qi = jax.lax.broadcasted_iota(jnp.int32, (1, q, 1), 1)
-        asc = ((qi * (2 * j)) // k) % 2 == 0
-        swap = jnp.where(asc, swap, ~swap)
-    nlo_v = jnp.where(swap, hi_v, lo_v)
-    nhi_v = jnp.where(swap, lo_v, hi_v)
-    nlo_i = jnp.where(swap, hi_i, lo_i)
-    nhi_i = jnp.where(swap, lo_i, hi_i)
-    v = jnp.stack([nlo_v, nhi_v], axis=2).reshape(b, w)
-    i = jnp.stack([nlo_i, nhi_i], axis=2).reshape(b, w)
-    return v, i
+        flip = (lane & k) == 0 if descending else (lane & k) != 0
+        swap = swap ^ flip
+    return jnp.where(swap, pv, v), jnp.where(swap, pi, i)
 
 
-def sort_pairs(v, i):
-    """Full bitonic sort of (b, w) pairs, ascending by (value, index).
+def sort_pairs(v, i, descending: bool = False):
+    """Full bitonic sort of (b, w) pairs by (value, index), ascending or
+    ``descending``.
 
     ``w`` must be a power of two.  log2(w)*(log2(w)+1)/2 vectorized
     compare-exchange passes; equal composite keys only arise between
@@ -115,7 +127,7 @@ def sort_pairs(v, i):
     while k <= w:
         j = k // 2
         while j >= 1:
-            v, i = _cx_pass(v, i, j, k)
+            v, i = _cx_pass(v, i, j, k, descending)
             j //= 2
         k *= 2
     return v, i
@@ -124,7 +136,7 @@ def sort_pairs(v, i):
 def merge_pairs(v, i):
     """Bitonic merge: (b, w) pairs forming a bitonic sequence -> ascending.
 
-    log2(w) passes.  Used on ``incumbent ++ reversed(candidates)``, which
+    log2(w) passes.  Used on ``incumbent ++ descending candidates``, which
     is ascending-then-descending and hence bitonic."""
     w = v.shape[-1]
     j = w // 2
@@ -135,7 +147,7 @@ def merge_pairs(v, i):
 
 
 def _topk_kernel(xi_ref, xj_ref, val_ref, idx_ref, *, metric, n_valid,
-                 block, block_z, kp, out_w):
+                 block, block_z):
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -158,22 +170,32 @@ def _topk_kernel(xi_ref, xj_ref, val_ref, idx_ref, *, metric, n_valid,
     bad = (rows >= n_valid) | (cols >= n_valid) | (rows == cols)
     cv = jnp.where(bad, jnp.inf, dt)
     ci = jnp.where(bad, _IDX_PAD, cols)
-    cv, ci = sort_pairs(cv, ci)
-    cv, ci = cv[:, :kp], ci[:, :kp]                      # tile's kp best
-    iv = val_ref[...][:, :kp]
-    ii = idx_ref[...][:, :kp]
-    mv = jnp.concatenate([iv, cv[:, ::-1]], axis=1)      # bitonic 2*kp
-    mi = jnp.concatenate([ii, ci[:, ::-1]], axis=1)
-    mv, mi = merge_pairs(mv, mi)
-    mv, mi = mv[:, :kp], mi[:, :kp]
-    pad = out_w - kp
-    if pad:
-        mv = jnp.concatenate(
-            [mv, jnp.full((block, pad), jnp.inf, jnp.float32)], axis=1)
-        mi = jnp.concatenate(
-            [mi, jnp.full((block, pad), _IDX_PAD, jnp.int32)], axis=1)
-    val_ref[...] = mv
-    idx_ref[...] = mi
+    fold_tile(val_ref, idx_ref, cv, ci)
+
+
+def fold_tile(val_ref, idx_ref, cv, ci):
+    """Fold one (b, w) candidate tile into the running best-list refs.
+
+    ``val_ref``/``idx_ref`` hold the (b, out_w) incumbent, ascending by
+    (value, index); ``cv``/``ci`` are the tile's values and global indices,
+    masked entries already (+inf, INT32_MAX).  Sorted descending, the tile
+    ends in its out_w best: those lanes after the incumbent form a bitonic
+    2*out_w sequence, and one merge keeps the out_w best of both.  This is
+    the whole selection contract: given the same values, the result is
+    that of ``core.knn._top_k_rows``."""
+    b, w = cv.shape
+    out_w = val_ref.shape[1]
+    cv, ci = sort_pairs(cv, ci, descending=True)
+    if w >= out_w:
+        cv, ci = cv[:, w - out_w:], ci[:, w - out_w:]
+    else:
+        fill = (b, out_w - w)
+        cv = jnp.concatenate([jnp.full(fill, jnp.inf, jnp.float32), cv], 1)
+        ci = jnp.concatenate([jnp.full(fill, _IDX_PAD, jnp.int32), ci], 1)
+    mv, mi = merge_pairs(jnp.concatenate([val_ref[...], cv], axis=1),
+                         jnp.concatenate([idx_ref[...], ci], axis=1))
+    val_ref[...] = mv[:, :out_w]
+    idx_ref[...] = mi[:, :out_w]
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -200,10 +222,10 @@ def topk_pallas(
     kp = next_pow2(max(k, 1))
     assert m % block == 0 and m % block_z == 0, (m, block, block_z)
     assert block_z == next_pow2(block_z) and block_z >= kp, (block_z, kp)
-    out_w = kp if interpret else max(-(-kp // _LANE) * _LANE, _LANE)
+    out_w = max(kp, _LANE)
     kernel = functools.partial(
         _topk_kernel, metric=metric, n_valid=n_valid, block=block,
-        block_z=block_z, kp=kp, out_w=out_w)
+        block_z=block_z)
     vals, idx = pl.pallas_call(
         kernel,
         grid=(m // block, m // block_z),   # col axis last: sequential fold

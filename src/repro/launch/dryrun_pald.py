@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed, engine
-from repro.core.distributed import shard_map_compat
 from repro.launch import hlo_analysis, mesh as meshlib
 
 # v5e VPU: 8 lanes x 128 sublanes x 4 ALUs x ~0.94 GHz ~= 3.85e12 op/s fp32.
@@ -74,8 +73,9 @@ def run_cell(n: int, multi_pod: bool, strategy: str, *, dtype=jnp.float32,
         )
         out_spec = spec_in
 
-    fn = jax.jit(shard_map_compat(
-        body, mesh=mesh, in_specs=spec_in, out_specs=out_spec
+    fn = jax.jit(jax.shard_map(
+        body, mesh=mesh, in_specs=spec_in, out_specs=out_spec,
+        check_vma=False,
     ))
     D = jax.ShapeDtypeStruct((n, n), dtype,
                              sharding=NamedSharding(mesh, spec_in))
@@ -85,8 +85,6 @@ def run_cell(n: int, multi_pod: bool, strategy: str, *, dtype=jnp.float32,
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax<0.5 returns [dict], newer dict
-        cost = cost[0] if cost else {}
     coll = hlo_analysis.collective_stats(compiled.as_text())
     mem = compiled.memory_analysis()
 

@@ -22,10 +22,7 @@ from typing import Any
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # non-deprecated home of the mesh context (jax >= 0.5)
-    from jax._src.mesh import thread_resources as _thread_resources
-except ImportError:  # pragma: no cover - older jax
-    from jax.interpreters.pxla import thread_resources as _thread_resources
+from jax._src.mesh import thread_resources as _thread_resources
 
 TENSOR_AXES = {"heads", "ff", "experts", "vocab", "mamba_inner", "mamba_heads"}
 # head-count axes: shard over 'model' only when the count divides the axis

@@ -264,18 +264,32 @@ def _valid_tile(v) -> bool:
     return float(v) == int(v) and int(v) > 0
 
 
-def _default_blocks(n: int, pass_: str) -> tuple[int, int]:
+def _default_blocks(n: int, pass_: str,
+                    impl: str | None = None) -> tuple[int, int]:
     """Size-aware fallback when nothing is cached (the old constants,
     clamped).  cohesion_tri keeps its whole (n, block_z) column slab in
     VMEM, so its z tile shrinks as n grows (~6 MiB budget).  The
     selection pass (pald_topk) defaults to the PR 5 contract — 1024-row
     slabs, tile = n i.e. direct full-width top_k (the tile-min prefilter
-    must be opted in or measured in; on clustered data direct wins)."""
+    must be opted in or measured in; on clustered data direct wins).
+
+    The ``pallas`` impl gets tiles that Mosaic compiles into the 16 MiB
+    of scoped VMEM a TPU kernel may use: a (128, 256) selection tile
+    (the bitonic network unrolls over the tile's lanes), 64-row knn
+    blocks (each holds a (64, 128, 128) gathered cube, lane-padded), z
+    tiles in whole 128-lane columns, and a tri z tile whose
+    double-buffered (n, block_z) slab stays within 8 MiB."""
+    pallas = impl == "pallas"
     if pass_ == "pald_topk":
+        if pallas:
+            return max(min(128, n), 1), max(min(256, n), 1)
         return max(min(1024, n), 1), max(n, 1)
-    block = min(128, n)
-    block_z = min(512, n)
-    if pass_ == "cohesion_tri" and n > 0:
+    block = min(64 if pallas and pass_ == "pald_knn" else 128, n)
+    # a Mosaic block spans whole 128-lane columns (or the whole padded axis)
+    block_z = min(512, -(-n // 128) * 128 if pallas else n)
+    if pass_ in ("cohesion_tri", "pald_tri") and pallas and n > 0:
+        block_z = min(block_z, max((8 << 20) // (8 * n) // 128 * 128, 128))
+    elif pass_ == "cohesion_tri" and n > 0:
         block_z = min(block_z, max((6 << 20) // (4 * n), 8))
     return max(block, 1), max(block_z, 1)
 
@@ -335,7 +349,7 @@ def resolve_blocks_ex(
             quarantined = quarantined or f"quarantined:{key}"
         elif rec is not None:
             quarantined = quarantined or f"quarantined:{key}"
-    b, bz = _default_blocks(n, pass_)
+    b, bz = _default_blocks(n, pass_, impl)
     return b, bz, quarantined or "default"
 
 

@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core.distributed import shard_map_compat
 from repro.launch import hlo_analysis as H
 from repro.launch import mesh as meshlib
 
@@ -43,7 +42,8 @@ def test_parser_on_real_compiled_module():
     def f(x):
         return jax.lax.psum(x, "data")
 
-    sharded = shard_map_compat(f, mesh=mesh, in_specs=P("data"), out_specs=P())
+    sharded = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(),
+                            check_vma=False)
     x = jax.ShapeDtypeStruct((8, 32), jnp.float32,
                              sharding=NamedSharding(mesh, P("data")))
     compiled = jax.jit(sharded).lower(x).compile()

@@ -1,13 +1,20 @@
 """Streaming top-k selection conformance (ISSUE 9).
 
-Every selection implementation — the streaming Pallas kernel (interpret
-mode on CPU), the jnp lax.map scan in both strategies (direct full-width
-top_k and the exact tile-min prefilter), and the host-driven chunked
-degradation rung — must be BITWISE identical to the reference
-``_top_k_rows`` contract: stable ``lax.top_k`` on negated distances,
+The selection contract is stated on distance VALUES: given the same
+distances, every selection implementation — the streaming Pallas kernel
+(interpret mode on CPU), the jnp lax.map scan in both strategies (direct
+full-width top_k and the exact tile-min prefilter), and the host-driven
+chunked degradation rung — is BITWISE identical to the reference
+``_top_k_rows``: stable ``lax.top_k`` on negated distances,
 lower-index-first tie-break, self excluded.  Selection feeds every
 downstream sparse result, so a one-ulp or one-rank divergence here is a
 silent correctness bug, not a tolerance question.
+
+Each path computes its own distances, with one f32 ``dist_tile`` whose d-sum
+order is the backend's (a tile GEMM and a slab GEMM may differ by an ulp on
+XLA:CPU).  So the network is tested on given distance tiles directly, and
+the paths are compared on integer-valued features, whose distances are
+exact in f32 and therefore identical on every path.
 
 The fused select->cohere pipeline is covered too: it must bitwise-equal
 the two-stage ``knn_from_features`` -> ``ops.pald_knn`` composition
@@ -18,20 +25,25 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import knn
 from repro.core.features import dist_tile
 from repro.kernels import ops
-from repro.kernels.pald_topk import topk_pallas
+from repro.kernels.pald_topk import fold_tile, next_pow2, topk_pallas
 
 METRICS = ("sqeuclidean", "euclidean", "cosine", "manhattan")
+_IMAX = np.iinfo(np.int32).max
 
 
 def _features(n, d, seed=0, with_dups=True):
+    """Small-integer features: every distance is exact in f32, so every
+    path sees identical values, with plenty of distance ties."""
     rng = np.random.default_rng(seed)
-    X = rng.normal(size=(n, d)).astype(np.float32)
+    X = rng.integers(-4, 5, size=(n, d)).astype(np.float32)
     if with_dups and n >= 8:
-        # duplicated rows force distance ties -> exercises the
+        # duplicated rows force zero-distance ties -> exercises the
         # lower-index-first tie-break in every implementation
         X[n // 3] = X[5]
         X[n - 2] = X[1]
@@ -43,13 +55,8 @@ def _reference(X, k, metric="euclidean", pad_to=None):
 
     ``pad_to`` computes the distances on a zero-row-padded (m, d) input
     with padded rows/cols masked out — the shape the Pallas kernel's
-    tiles see.  Zero-padded ROWS are excluded by masking, but on XLA:CPU
-    the distance GEMM itself is only bitwise-stable across shapes for
-    SIMD-clean d (the d=4/8 used below); for ragged d the padded GEMM
-    can differ from the unpadded one by 1 ulp (Eigen packing), which is
-    an XLA property, not a selection bug — on the TPU MXU the per-pair
-    contraction order is fixed by d alone.  Tests that exercise ragged d
-    therefore compare against the same-shape reference."""
+    tiles see, so that float features with a shape-dependent GEMM order
+    still give the kernel and the reference identical values."""
     n = X.shape[0]
     m = pad_to or n
     Xp = np.zeros((m, X.shape[1]), np.float32)
@@ -60,6 +67,44 @@ def _reference(X, k, metric="euclidean", pad_to=None):
     bad = (ids[:, None] == ids[None, :]) | (ids[None, :] >= n)
     dv, di = knn._top_k_rows(jnp.where(bad, -jnp.inf, -D), k)
     return dv[:n], di[:n]
+
+
+def _fold_rows(D, k, block_z, order=None):
+    """Run the kernel's selection network alone over given distances.
+
+    ``D`` (b, n) is folded tile by tile (``order`` permutes the visit
+    order) through ``pald_topk.fold_tile``; +inf entries are masked, as the
+    kernel masks self and padding."""
+    b, n = D.shape
+    nt = n // block_z
+    order = jnp.asarray(np.arange(nt) if order is None else order, jnp.int32)
+    out_w = max(next_pow2(k), 128)
+
+    def kern(order_ref, d_ref, v_ref, i_ref):
+        t = pl.program_id(0)
+
+        @pl.when(t == 0)
+        def _init():
+            v_ref[...] = jnp.full_like(v_ref, jnp.inf)
+            i_ref[...] = jnp.full_like(i_ref, _IMAX)
+
+        cv = d_ref[...]
+        cols = order_ref[t] * block_z + jax.lax.broadcasted_iota(
+            jnp.int32, cv.shape, 1)
+        fold_tile(v_ref, i_ref, cv, jnp.where(jnp.isinf(cv), _IMAX, cols))
+
+    spec = pl.BlockSpec((b, out_w), lambda t, o: (0, 0))
+    vals, idx = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nt,),
+            in_specs=[pl.BlockSpec((b, block_z), lambda t, o: (0, o[t]))],
+            out_specs=[spec, spec]),
+        out_shape=[jax.ShapeDtypeStruct((b, out_w), jnp.float32),
+                   jax.ShapeDtypeStruct((b, out_w), jnp.int32)],
+        interpret=True,
+    )(order, D)
+    return vals[:, :k], idx[:, :k]
 
 
 def _check(graph, ref_d, ref_i):
@@ -114,7 +159,8 @@ def test_edge_k_all_impls(k):
 
 
 def test_kernel_direct_entry_matches_top_k_rows():
-    """topk_pallas itself (below the ops facade), prime n, RAGGED d.
+    """topk_pallas itself (below the ops facade), prime n, RAGGED d, float
+    features.
 
     d=5 makes the distance GEMM shape-sensitive on XLA:CPU, so the
     reference is computed at the kernel's own padded shape (see
@@ -122,7 +168,8 @@ def test_kernel_direct_entry_matches_top_k_rows():
     machinery — self/pad masking, bitonic merge, tie-break — adds zero
     error for any d."""
     n, d, k = 97, 5, 13
-    X = _features(n, d)
+    X = np.random.default_rng(0).normal(size=(n, d)).astype(np.float32)
+    X[n // 3] = X[5]
     m = 128  # pad to one 128-row block
     for metric in METRICS:
         ref_d, ref_i = _reference(X, k, metric, pad_to=m)
@@ -206,3 +253,22 @@ def test_fused_engine_path_matches_two_stage_dense():
     ref = knn.scatter_dense(graph, vals)
     out = pald.from_features(X, k=k, normalize=False)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("k, block_z", [(1, 16), (9, 32), (33, 64),
+                                        (102, 128), (130, 256)])
+def test_network_matches_top_k_rows_on_given_tiles(k, block_z):
+    """The selection contract on given values: float distances with exact
+    ties and masked (+inf) entries, folded tile by tile in a shuffled
+    order, equal ``_top_k_rows`` on the same values bit for bit."""
+    rng = np.random.default_rng(k)
+    b, n = 16, 512
+    D = rng.random((b, n)).astype(np.float32)
+    D[:, ::7] = np.round(D[:, ::7], 1)             # many exact ties
+    D[rng.random((b, n)) < 0.05] = np.inf          # masked entries
+    order = rng.permutation(n // block_z)
+    vals, idx = _fold_rows(jnp.asarray(D), k, block_z, order)
+    ref_v, ref_i = knn._top_k_rows(-jnp.asarray(D), k)
+    ref_i = jnp.where(jnp.isinf(ref_v), _IMAX, ref_i)
+    np.testing.assert_array_equal(np.asarray(vals), np.asarray(ref_v))
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ref_i))
