@@ -7,10 +7,16 @@ Follows Berenhaut, Moore & Melvin (PNAS 2022), the paper's reference [2]:
 * the strong-tie matrix keeps symmetrized cohesion min(c_xy, c_yx) where it
   exceeds tau;
 * communities are the connected components of the strong-tie graph.
+
+``communities`` runs its two host stages inside profiler spans
+(``jax.profiler.TraceAnnotation``): ``analysis.strong_ties`` and
+``analysis.components`` (the edge list and the union-find).  A span
+records nothing unless a trace is running.
 """
 from __future__ import annotations
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["universal_threshold", "strong_ties", "communities",
            "connected_components", "top_ties"]
@@ -85,8 +91,10 @@ def communities(C: np.ndarray, threshold: float | None = None) -> list[list[int]
         >>> communities(C)
         [[0, 1], [2]]
     """
-    S = strong_ties(C, threshold)
-    return connected_components(S.shape[0], zip(*np.nonzero(S)))
+    with TraceAnnotation("analysis.strong_ties"):
+        S = strong_ties(C, threshold)
+    with TraceAnnotation("analysis.components"):
+        return connected_components(S.shape[0], zip(*np.nonzero(S)))
 
 
 def connected_components(n: int, edges) -> list[list[int]]:

@@ -34,10 +34,18 @@ that (DESIGN.md §11):
 
 ``pald.cohesion`` / ``pald.from_features`` are thin facades over
 ``plan(...).execute(x)``; they contain no method branching.
+
+Profiler spans (``jax.profiler.TraceAnnotation``) mark the stages of a call
+in a trace: ``engine.plan`` (the whole resolution, tuning cache included),
+``engine.validate`` (the input checks of ``execute``) and
+``engine.execute`` (the executor dispatch, parent of the ``pipeline.*`` and
+``kernel.*`` spans of ``kernels/ops``).  A span records nothing unless a
+trace is running.  Under a caller's ``jax.jit`` they mark tracing only.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable
 
 import numpy as np
@@ -241,13 +249,16 @@ class PaldPlan:
         recorded in ``explain()["degradations"]``.
         """
         x = jnp.asarray(x)
-        _check_input(x, self)
-        if self.on_error == "fallback":
-            return _res.execute_plan(self, x)
-        _res.fault_point("engine.execute", kind=self.kind, method=self.method,
-                         schedule=self.schedule, impl=self.impl)
-        fn = get_executor(self.kind, self.method, self.schedule)
-        return run_batched(fn, x, self, self.batch)
+        with jax.profiler.TraceAnnotation("engine.validate"):
+            _check_input(x, self)
+        with jax.profiler.TraceAnnotation("engine.execute"):
+            if self.on_error == "fallback":
+                return _res.execute_plan(self, x)
+            _res.fault_point("engine.execute", kind=self.kind,
+                             method=self.method, schedule=self.schedule,
+                             impl=self.impl)
+            fn = get_executor(self.kind, self.method, self.schedule)
+            return run_batched(fn, x, self, self.batch)
 
     # -- distributed shard-body primitives ---------------------------------
     # The shard bodies in core/distributed.py call the rectangular kernel
@@ -546,6 +557,7 @@ def _default_kernel_impl(method: str) -> str:
     return "jnp" if method in ("fused", "knn") else "interpret"
 
 
+@functools.partial(jax.profiler.annotate_function, name="engine.plan")
 def plan(
     x=None,
     *,

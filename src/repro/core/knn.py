@@ -66,6 +66,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from .weights import (DEFAULT_TIES, focus_weight, resolve_weight,
                       support_weight)
@@ -385,7 +386,8 @@ def communities(graph: NeighborGraph, values: np.ndarray,
     """Connected components of the sparse strong-tie graph.
 
     Same output contract as ``analysis.communities``: components sorted
-    by size (largest first, ties by smallest member), members ascending.
+    by size (largest first, ties by smallest member), members ascending,
+    and the same profiler spans around its two host stages.
 
     Example:
         >>> import jax.numpy as jnp
@@ -398,5 +400,7 @@ def communities(graph: NeighborGraph, values: np.ndarray,
     """
     from .analysis import connected_components
 
-    src, dst, _ = strong_ties(graph, values, threshold)
-    return connected_components(graph.indices.shape[0], zip(src, dst))
+    with TraceAnnotation("analysis.strong_ties"):
+        src, dst, _ = strong_ties(graph, values, threshold)
+    with TraceAnnotation("analysis.components"):
+        return connected_components(graph.indices.shape[0], zip(src, dst))

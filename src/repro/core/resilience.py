@@ -424,9 +424,10 @@ def execute_plan(plan, x):
         (f"primary({plan.impl or plan.method})", original)]
     for step in chain_for(plan):
         try:
-            result, batch = _run_with_oom_retries(
-                lambda xi, b, s=step: s.run(xi, plan, b), x, plan, batch,
-                cell, step.label)
+            with jax.profiler.TraceAnnotation("resilience.step"):
+                result, batch = _run_with_oom_retries(
+                    lambda xi, b, s=step: s.run(xi, plan, b), x, plan,
+                    batch, cell, step.label)
         except Exception as step_exc:  # noqa: BLE001
             attempts.append((step.label, step_exc))
             continue

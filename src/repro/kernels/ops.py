@@ -29,6 +29,15 @@ global-index tiebreak of ``needs_index_tiebreak`` functionals either as an
 explicit ``xwins`` array (distributed callers own traced offsets) or as
 static ``xw_offsets`` it derives per tile; the square and fused forms
 derive it themselves.
+
+Every stage of the eager pipelines runs inside a profiler span
+(``jax.profiler.TraceAnnotation``) named ``<layer>.<stage>``:
+``pipeline.pad``, ``pipeline.weights``, ``pipeline.gather_cube``,
+``pipeline.scatter_dense``, ``pipeline.finish``, and ``kernel.<kernel>``
+around each Pallas kernel call.  A span records nothing unless a profiler
+trace is running; in a trace, every device program a stage launches can be
+put down to it.  Under a caller's ``jax.jit`` (or ``shard_map``) these
+functions run once, while tracing, so the spans mark tracing only.
 """
 from __future__ import annotations
 
@@ -36,6 +45,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.resilience import fault_point
 from repro.core.weights import (DEFAULT_TIES, focus_weight, index_xwins,
@@ -396,14 +406,17 @@ def focus_general(DXZ, DYZ, DXY, *, block=128, block_z=512,
     bx, mxp = _block_and_pad(mx, block)
     by, myp = _block_and_pad(my, block)
     bz, mzp = _block_and_pad(mz, block_z)
-    U = focus_general_pallas(
-        _pad2(DXZ, mxp, mzp, jnp.inf),
-        _pad2(DYZ, myp, mzp, jnp.inf),
-        _pad2(DXY, mxp, myp, jnp.inf),
-        block_x=bx, block_y=by, block_z=bz, interpret=impl == "interpret",
-        ties=ties,
-    )
-    return U[:mx, :my]
+    with TraceAnnotation("pipeline.pad"):
+        padded = (_pad2(DXZ, mxp, mzp, jnp.inf),
+                  _pad2(DYZ, myp, mzp, jnp.inf),
+                  _pad2(DXY, mxp, myp, jnp.inf))
+    with TraceAnnotation("kernel.focus"):
+        U = focus_general_pallas(
+            *padded, block_x=bx, block_y=by, block_z=bz,
+            interpret=impl == "interpret", ties=ties,
+        )
+    with TraceAnnotation("pipeline.finish"):
+        return U[:mx, :my]
 
 
 def cohesion_general(DXZ, DYZ, DXY, W, *, block=128, block_z=512,
@@ -435,23 +448,25 @@ def cohesion_general(DXZ, DYZ, DXY, W, *, block=128, block_z=512,
     by, myp = _block_and_pad(my, block)
     bz, mzp = _block_and_pad(mz, block_z)
     XW = offs = None
-    if ties.needs_index_tiebreak:
-        if xwins is not None:
-            # pad with 0 ("x does not win"): padded pairs carry zero weight
-            XW = _pad2(xwins.astype(jnp.float32), mxp, myp, 0.0)
-        else:
-            # per-tile in-kernel derivation from the static global offsets
-            offs = (int(xw_offsets[0]), int(xw_offsets[1]))
-    C = cohesion_general_pallas(
-        _pad2(DXZ, mxp, mzp, jnp.inf),
-        _pad2(DYZ, myp, mzp, jnp.inf),
-        _pad2(DXY, mxp, myp, jnp.inf),
-        _pad2(W, mxp, myp, 0.0),
-        XW,
-        block_x=bx, block_z=bz, block_y=by, interpret=impl == "interpret",
-        ties=ties, xw_offsets=offs,
-    )
-    return C[:mx, :mz]
+    with TraceAnnotation("pipeline.pad"):
+        if ties.needs_index_tiebreak:
+            if xwins is not None:
+                # pad with 0 ("x does not win"): padded pairs carry zero weight
+                XW = _pad2(xwins.astype(jnp.float32), mxp, myp, 0.0)
+            else:
+                # per-tile in-kernel derivation from the static global offsets
+                offs = (int(xw_offsets[0]), int(xw_offsets[1]))
+        padded = (_pad2(DXZ, mxp, mzp, jnp.inf),
+                  _pad2(DYZ, myp, mzp, jnp.inf),
+                  _pad2(DXY, mxp, myp, jnp.inf),
+                  _pad2(W, mxp, myp, 0.0))
+    with TraceAnnotation("kernel.cohesion"):
+        C = cohesion_general_pallas(
+            *padded, XW, block_x=bx, block_z=bz, block_y=by,
+            interpret=impl == "interpret", ties=ties, xw_offsets=offs,
+        )
+    with TraceAnnotation("pipeline.finish"):
+        return C[:mx, :mz]
 
 
 def focus(D, *, block=128, block_z=512, impl: str | None = None,
@@ -467,18 +482,22 @@ def focus(D, *, block=128, block_z=512, impl: str | None = None,
                                          ties)
         block, block_z = min(block, n), min(block_z, n)
         if impl == "jnp":
-            Dp, _, n0 = _pad_square_tri(D, None, block)
+            with TraceAnnotation("pipeline.pad"):
+                Dp, _, n0 = _pad_square_tri(D, None, block)
             return _focus_tri_jnp(Dp, block=block, ties=ties)[:n0, :n0]
         # pad to the largest tile, then shrink tiles to divisors of the
         # padded size (keeps the quantum bounded — never an lcm blow-up)
-        Dp, _, n0 = _pad_square_tri(D, None, max(block, block_z))
+        with TraceAnnotation("pipeline.pad"):
+            Dp, _, n0 = _pad_square_tri(D, None, max(block, block_z))
         m = Dp.shape[0]
         block, block_z = _pick_block(m, block), _pick_block(m, block_z)
-        U = focus_tri_pallas(
-            Dp, block=block, block_z=block_z, interpret=impl == "interpret",
-            ties=ties,
-        )
-        return U[:n0, :n0]
+        with TraceAnnotation("kernel.focus_tri"):
+            U = focus_tri_pallas(
+                Dp, block=block, block_z=block_z,
+                interpret=impl == "interpret", ties=ties,
+            )
+        with TraceAnnotation("pipeline.finish"):
+            return U[:n0, :n0]
     return focus_general(D, D, D, block=block, block_z=block_z, impl=impl,
                          ties=ties)
 
@@ -499,16 +518,20 @@ def cohesion_from_weights(D, W, *, block=128, block_z=512, impl: str | None = No
                                          impl, ties)
         block, block_z = min(block, n), min(block_z, n)
         if impl == "jnp":
-            Dp, Wp, n0 = _pad_square_tri(D, W, block)
+            with TraceAnnotation("pipeline.pad"):
+                Dp, Wp, n0 = _pad_square_tri(D, W, block)
             return _cohesion_tri_jnp(Dp, Wp, block=block, ties=ties)[:n0, :n0]
-        Dp, Wp, n0 = _pad_square_tri(D, W, max(block, block_z))
+        with TraceAnnotation("pipeline.pad"):
+            Dp, Wp, n0 = _pad_square_tri(D, W, max(block, block_z))
         m = Dp.shape[0]
         block, block_z = _pick_block(m, block), _pick_block(m, block_z)
-        C = cohesion_tri_pallas(
-            Dp, Wp, block=block, block_z=block_z, interpret=impl == "interpret",
-            ties=ties,
-        )
-        return C[:n0, :n0]
+        with TraceAnnotation("kernel.cohesion_tri"):
+            C = cohesion_tri_pallas(
+                Dp, Wp, block=block, block_z=block_z,
+                interpret=impl == "interpret", ties=ties,
+            )
+        with TraceAnnotation("pipeline.finish"):
+            return C[:n0, :n0]
     offs = (0, 0) if ties.needs_index_tiebreak else None
     return cohesion_general(D, D, D, W, block=block, block_z=block_z, impl=impl,
                             ties=ties, xw_offsets=offs)
@@ -539,11 +562,13 @@ def pald(
                         n_valid=n_valid, impl=impl, ties=ties)
     impl = impl or ("pallas" if on_tpu() else "interpret")
     U = focus(D, block=block, block_z=block_z, impl=impl, ties=ties)
-    W = weights_ref(U, n_valid)
+    with TraceAnnotation("pipeline.weights"):
+        W = weights_ref(U, n_valid)
     C = cohesion_from_weights(D, W, block=block, block_z=block_z, impl=impl,
                               ties=ties)
     if normalize:
-        C = C / (D.shape[0] - 1)
+        with TraceAnnotation("pipeline.finish"):
+            C = C / (D.shape[0] - 1)
     return C
 
 
@@ -579,32 +604,40 @@ def pald_fused(
     block, block_z, _ = _tuner.resolve_fused_tiles(n, d, block, block_z,
                                                    impl=impl, ties=ties)
     if impl == "jnp":
-        Xp, n0 = pad_features(X, block)
+        with TraceAnnotation("pipeline.pad"):
+            Xp, n0 = pad_features(X, block)
         U = _focus_fused_jnp(Xp, metric=metric, block=block, block_z=block_z,
                              n_valid=n0, ties=ties)
-        W = weights_ref(U, n0 if Xp.shape[0] != n0 else None)
+        with TraceAnnotation("pipeline.weights"):
+            W = weights_ref(U, n0 if Xp.shape[0] != n0 else None)
         C = _cohesion_fused_jnp(Xp, W, metric=metric, block=block,
                                 block_z=block_z, n_valid=n0, ties=ties)
     else:
         from .pald_fused import cohesion_fused_pallas, focus_fused_pallas
 
-        Xp, n0 = pad_features(X, max(block, block_z))
+        with TraceAnnotation("pipeline.pad"):
+            Xp, n0 = pad_features(X, max(block, block_z))
+            if impl == "pallas" and d % 128:
+                # zero feature columns are exact no-ops for every metric; pad d
+                # to the lane quantum so Mosaic gets aligned (block, d) tiles
+                Xp = jnp.pad(Xp, ((0, 0), (0, 128 - d % 128)))
         m = Xp.shape[0]
         block, block_z = _pick_block(m, block), _pick_block(m, block_z)
-        if impl == "pallas" and d % 128:
-            # zero feature columns are exact no-ops for every metric; pad d
-            # to the lane quantum so Mosaic gets aligned (block, d) tiles
-            Xp = jnp.pad(Xp, ((0, 0), (0, 128 - d % 128)))
         interp = impl == "interpret"
-        U = focus_fused_pallas(Xp, metric=metric, n_valid=n0, block=block,
-                               block_z=block_z, interpret=interp, ties=ties)
-        W = weights_ref(U, n0 if m != n0 else None)
-        C = cohesion_fused_pallas(Xp, W, metric=metric, n_valid=n0,
-                                  block=block, block_z=block_z,
-                                  interpret=interp, ties=ties)
-    C = C[:n, :n]
-    if normalize:
-        C = C / max(n - 1, 1)
+        with TraceAnnotation("kernel.focus_fused"):
+            U = focus_fused_pallas(Xp, metric=metric, n_valid=n0, block=block,
+                                   block_z=block_z, interpret=interp,
+                                   ties=ties)
+        with TraceAnnotation("pipeline.weights"):
+            W = weights_ref(U, n0 if m != n0 else None)
+        with TraceAnnotation("kernel.cohesion_fused"):
+            C = cohesion_fused_pallas(Xp, W, metric=metric, n_valid=n0,
+                                      block=block, block_z=block_z,
+                                      interpret=interp, ties=ties)
+    with TraceAnnotation("pipeline.finish"):
+        C = C[:n, :n]
+        if normalize:
+            C = C / max(n - 1, 1)
     return C
 
 
@@ -634,25 +667,31 @@ def pald_tri(
     # one pipeline-level pad to the largest requested tile, then shrink each
     # tile to a divisor of the padded size (bounded quantum, no lcm blow-up)
     tiles = (bf, bc) if impl == "jnp" else (bf, bc, bzf, bzc)
-    Dp, _, _ = _pad_square_tri(D, None, max(tiles))
+    with TraceAnnotation("pipeline.pad"):
+        Dp, _, _ = _pad_square_tri(D, None, max(tiles))
     m = Dp.shape[0]
     bf, bc = _pick_block(m, bf), _pick_block(m, bc)
     bzf, bzc = _pick_block(m, bzf), _pick_block(m, bzc)
     nv = n_valid if n_valid is not None else (n_in if Dp.shape[0] != n_in else None)
     if impl == "jnp":
         U = _focus_tri_jnp(Dp, block=bf, ties=ties)
-        W = weights_ref(U, nv)
+        with TraceAnnotation("pipeline.weights"):
+            W = weights_ref(U, nv)
         C = _cohesion_tri_jnp(Dp, W, block=bc, ties=ties)
     else:
         interp = impl == "interpret"
-        U = focus_tri_pallas(Dp, block=bf, block_z=bzf, interpret=interp,
-                             ties=ties)
-        W = weights_ref(U, nv)
-        C = cohesion_tri_pallas(Dp, W, block=bc, block_z=bzc, interpret=interp,
-                                ties=ties)
-    C = C[:n_in, :n_in]
-    if normalize:
-        C = C / (n_in - 1)
+        with TraceAnnotation("kernel.focus_tri"):
+            U = focus_tri_pallas(Dp, block=bf, block_z=bzf, interpret=interp,
+                                 ties=ties)
+        with TraceAnnotation("pipeline.weights"):
+            W = weights_ref(U, nv)
+        with TraceAnnotation("kernel.cohesion_tri"):
+            C = cohesion_tri_pallas(Dp, W, block=bc, block_z=bzc,
+                                    interpret=interp, ties=ties)
+    with TraceAnnotation("pipeline.finish"):
+        C = C[:n_in, :n_in]
+        if normalize:
+            C = C / (n_in - 1)
     return C
 
 
@@ -735,28 +774,33 @@ def knn_values(
                                          k=k)
     block = max(min(int(block), n), 1)
     m = -(-n // block) * block
-    dn_p = _pad2(graph.distances.astype(jnp.float32), m, k, jnp.inf)
-    idx_p = _pad2(graph.indices, m, k, 0)
+    with TraceAnnotation("pipeline.pad"):
+        dn_p = _pad2(graph.distances.astype(jnp.float32), m, k, jnp.inf)
+        idx_p = _pad2(graph.indices, m, k, 0)
     if impl == "jnp":
         vals = _knn_values_jnp(x, dn_p, idx_p, kind=kind, metric=metric,
                                block=block, ties=ties)
-        return vals[:n]
+        with TraceAnnotation("pipeline.finish"):
+            return vals[:n]
     from .pald_knn import knn_values_pallas
 
-    g = _gather_tiles(x, idx_p, kind, metric)          # (m, k, k), real k
-    kp = k if impl == "interpret" else -(-k // 128) * 128
-    if kp != k:
-        # lane-pad the neighbor axis AFTER gathering (a pre-pad gather
-        # would stage and recompute a (kp/k)^2-times-larger cube): +inf
-        # pair distances, index 0, zero gathered distances — the kernel
-        # masks every padded column out of the focus count and pair
-        # weights via k_valid
-        dn_p = _pad2(dn_p, m, kp, jnp.inf)
-        idx_p = _pad2(idx_p, m, kp, 0)
-        g = jnp.pad(g, ((0, 0), (0, kp - k), (0, kp - k)))
-    vals = knn_values_pallas(dn_p, g, idx_p, block=block, k_valid=k,
-                             ties=ties, interpret=impl == "interpret")
-    return vals[:n, :k + 1]
+    with TraceAnnotation("pipeline.gather_cube"):
+        g = _gather_tiles(x, idx_p, kind, metric)      # (m, k, k), real k
+        kp = k if impl == "interpret" else -(-k // 128) * 128
+        if kp != k:
+            # lane-pad the neighbor axis AFTER gathering (a pre-pad gather
+            # would stage and recompute a (kp/k)^2-times-larger cube): +inf
+            # pair distances, index 0, zero gathered distances — the kernel
+            # masks every padded column out of the focus count and pair
+            # weights via k_valid
+            dn_p = _pad2(dn_p, m, kp, jnp.inf)
+            idx_p = _pad2(idx_p, m, kp, 0)
+            g = jnp.pad(g, ((0, 0), (0, kp - k), (0, kp - k)))
+    with TraceAnnotation("kernel.knn_values"):
+        vals = knn_values_pallas(dn_p, g, idx_p, block=block, k_valid=k,
+                                 ties=ties, interpret=impl == "interpret")
+    with TraceAnnotation("pipeline.finish"):
+        return vals[:n, :k + 1]
 
 
 def pald_knn(
@@ -816,7 +860,8 @@ def pald_knn(
     vals = knn_values(x, graph, kind=kind, metric=metric, block=block,
                       impl=impl, ties=ties)
     if normalize:
-        vals = vals / max(n - 1, 1)
+        with TraceAnnotation("pipeline.finish"):
+            vals = vals / max(n - 1, 1)
     return graph, vals
 
 
@@ -1017,11 +1062,13 @@ def topk_select(
     if impl == "jnp":
         chunk = block
         m = -(-n // chunk) * chunk
-        Xp = jnp.pad(X, ((0, m - n), (0, 0)))
+        with TraceAnnotation("pipeline.pad"):
+            Xp = jnp.pad(X, ((0, m - n), (0, 0)))
         dv, di = _topk_select_jnp(Xp, k=k, metric=metric, chunk=chunk, n=n,
                                   tile=tile)
-        return _knn.NeighborGraph(di.reshape(m, k)[:n],
-                                  dv.reshape(m, k)[:n])
+        with TraceAnnotation("pipeline.finish"):
+            return _knn.NeighborGraph(di.reshape(m, k)[:n],
+                                      dv.reshape(m, k)[:n])
     # pallas / interpret: power-of-two candidate tile >= next_pow2(k),
     # rows padded to a multiple of both tiles (masked off via n_valid)
     kp = _next_pow2(k)
@@ -1033,10 +1080,13 @@ def topk_select(
     blk = min(blk, _next_pow2(n))
     q = max(blk, bz)                  # both pow2: lcm == max
     m = -(-n // q) * q
-    Xp = jnp.pad(X, ((0, m - n), (0, 0)))
-    dv, di = topk_pallas(Xp, k=k, metric=metric, n_valid=n, block=blk,
-                         block_z=bz, interpret=impl == "interpret")
-    return _knn.NeighborGraph(di[:n], dv[:n])
+    with TraceAnnotation("pipeline.pad"):
+        Xp = jnp.pad(X, ((0, m - n), (0, 0)))
+    with TraceAnnotation("kernel.topk"):
+        dv, di = topk_pallas(Xp, k=k, metric=metric, n_valid=n, block=blk,
+                             block_z=bz, interpret=impl == "interpret")
+    with TraceAnnotation("pipeline.finish"):
+        return _knn.NeighborGraph(di[:n], dv[:n])
 
 
 # --------------------------------------------------------------------------
@@ -1124,14 +1174,16 @@ def select_cohere(
         block, tile = _resolve_topk_tiles(n, d, k, block, tile, sel)
         chunk = block
         m = -(-n // chunk) * chunk
-        Xp = jnp.pad(X, ((0, m - n), (0, 0)))
+        with TraceAnnotation("pipeline.pad"):
+            Xp = jnp.pad(X, ((0, m - n), (0, 0)))
         fault_point("ops.topk_select", impl=sel, metric=metric)
         dv, di, vals = _select_cohere_jnp(Xp, k=k, metric=metric,
                                           chunk=chunk, n=n, tile=tile,
                                           ties=ties)
-        graph = _knn.NeighborGraph(di.reshape(m, k)[:n],
-                                   dv.reshape(m, k)[:n])
-        vals = vals.reshape(m, k + 1)[:n]
+        with TraceAnnotation("pipeline.finish"):
+            graph = _knn.NeighborGraph(di.reshape(m, k)[:n],
+                                       dv.reshape(m, k)[:n])
+            vals = vals.reshape(m, k + 1)[:n]
     else:
         # two kernels back-to-back: device arrays flow straight through
         graph = topk_select(X, k, metric=metric, impl=sel, block=block,
@@ -1139,7 +1191,8 @@ def select_cohere(
         vals = knn_values(X, graph, kind="features", metric=metric,
                           block=cohere_block, impl=impl, ties=ties)
     if normalize:
-        vals = vals / max(n - 1, 1)
+        with TraceAnnotation("pipeline.finish"):
+            vals = vals / max(n - 1, 1)
     return graph, vals
 
 
@@ -1153,13 +1206,15 @@ from repro.core import engine as _engine  # noqa: E402  (registry import)
 
 
 def _kernel_exec(D, plan, pipeline):
-    Dp, n0 = _engine.pad_distance_matrix(D, plan.block)  # f32 boundary cast
+    with TraceAnnotation("pipeline.pad"):
+        Dp, n0 = _engine.pad_distance_matrix(D, plan.block)  # f32 boundary
     nv = jnp.asarray(n0) if Dp.shape[0] != n0 else None
     kz = {} if plan.block_z is None else {"block_z": plan.block_z}
     C = pipeline(Dp, block=plan.block, n_valid=nv, impl=plan.impl,
                  ties=plan.weight, **kz)
-    C = C[:n0, :n0]
-    return C / max(n0 - 1, 1) if plan.normalize else C
+    with TraceAnnotation("pipeline.finish"):
+        C = C[:n0, :n0]
+        return C / max(n0 - 1, 1) if plan.normalize else C
 
 
 @_engine.register_executor("distance", "kernel", "dense")
@@ -1192,6 +1247,15 @@ def _knn_dense_fallback(D, plan):
     return _engine.get_executor("distance", "dense", "dense")(D, plan)
 
 
+def _dense_from_sparse(graph, vals, plan):
+    """The dense (n, n) C users get from a knn cell's sparse values."""
+    with TraceAnnotation("pipeline.scatter_dense"):
+        C = _knn.scatter_dense(graph, vals)
+    with TraceAnnotation("pipeline.finish"):
+        n = C.shape[0]
+        return C / max(n - 1, 1) if plan.normalize else C
+
+
 @_engine.register_executor("distance", "knn", "dense")
 def _exec_knn_distance(D, plan):
     D = jnp.asarray(D, jnp.float32)
@@ -1204,8 +1268,7 @@ def _exec_knn_distance(D, plan):
         graph = _knn_from_distances_chunked(D, plan.k)
     graph, vals = pald_knn(D, k=plan.k, kind="distance", block=plan.block,
                            impl=plan.impl, ties=plan.weight, graph=graph)
-    C = _knn.scatter_dense(graph, vals)
-    return C / max(n - 1, 1) if plan.normalize else C
+    return _dense_from_sparse(graph, vals, plan)
 
 
 @_engine.register_executor("features", "knn", "dense")
@@ -1229,13 +1292,11 @@ def _exec_knn_features(X, plan):
             weight=plan.weight, block=plan.select_block or "auto",
             tile=plan.select_tile if plan.select_tile is not None
             else "auto", on_error="raise")
-        C = _knn.scatter_dense(graph, vals)
-        return C / max(n - 1, 1) if plan.normalize else C
+        return _dense_from_sparse(graph, vals, plan)
     graph, vals = select_cohere(
         X, k=plan.k, metric=plan.metric,
         block=plan.select_block or "auto",
         tile=plan.select_tile if plan.select_tile is not None else "auto",
         cohere_block=plan.block, impl=plan.impl, select=plan.select,
         ties=plan.weight)
-    C = _knn.scatter_dense(graph, vals)
-    return C / max(n - 1, 1) if plan.normalize else C
+    return _dense_from_sparse(graph, vals, plan)

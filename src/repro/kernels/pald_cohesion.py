@@ -168,6 +168,7 @@ def cohesion_general_pallas(
         out_shape=jax.ShapeDtypeStruct((mx, mz), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_y, block_x), jnp.float32)] * n_scratch,
         interpret=interpret,
+        name="cohesion_pallas",
     )(*args)
 
 

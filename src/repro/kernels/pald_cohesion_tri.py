@@ -173,5 +173,6 @@ def cohesion_tri_pallas(
             jax.ShapeDtypeStruct((n, n), jnp.float32),
         ],
         interpret=interpret,
+        name="cohesion_tri_pallas",
     )(xs, ys, D, D, D, W)
     return Cx + Cy

@@ -80,6 +80,7 @@ def focus_general_pallas(
         out_shape=jax.ShapeDtypeStruct((mx, my), jnp.float32),
         scratch_shapes=[pltpu.VMEM((block_y, block_x), jnp.float32)] * 2,
         interpret=interpret,
+        name="focus_pallas",
     )(DXZ.astype(jnp.float32), DYZ.astype(jnp.float32), DXY.astype(jnp.float32))
 
 

@@ -89,6 +89,7 @@ def focus_tri_pallas(
         grid_spec=spec,
         out_shape=jax.ShapeDtypeStruct((npairs, block, block), jnp.float32),
         interpret=interpret,
+        name="focus_tri_pallas",
     )(xs, ys, D, D, D)
 
     # mirror the compacted upper-tri blocks into the square U (O(n^2) move)

@@ -99,6 +99,7 @@ def focus_fused_pallas(
         scratch_shapes=[pltpu.VMEM((block_y, block_z), jnp.float32)]
         + [pltpu.VMEM((block_y, block), jnp.float32)] * 2,
         interpret=interpret,
+        name="focus_fused_pallas",
     )(X.astype(jnp.float32), X.astype(jnp.float32), X.astype(jnp.float32))
 
 
@@ -171,5 +172,6 @@ def cohesion_fused_pallas(
         scratch_shapes=[pltpu.VMEM((block_y, block_z), jnp.float32)]
         + [pltpu.VMEM((block_y, block), jnp.float32)] * 2,
         interpret=interpret,
+        name="cohesion_fused_pallas",
     )(X.astype(jnp.float32), X.astype(jnp.float32), X.astype(jnp.float32),
       W.astype(jnp.float32))

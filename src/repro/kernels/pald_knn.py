@@ -105,4 +105,5 @@ def knn_values_pallas(
         out_specs=pl.BlockSpec((block, n_cols), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, n_cols), jnp.float32),
         interpret=interpret,
+        name="knn_values_pallas",
     )(dn.astype(jnp.float32), g.astype(jnp.float32), idx.astype(jnp.int32))

@@ -242,5 +242,6 @@ def topk_pallas(
             jax.ShapeDtypeStruct((m, out_w), jnp.int32),
         ],
         interpret=interpret,
+        name="topk_pallas",
     )(X.astype(jnp.float32), X.astype(jnp.float32))
     return vals[:, :k], idx[:, :k]

@@ -9,6 +9,7 @@ module-scoped fixture, never at import, so every test worker collects the
 same tests and only the worker running this file loads the TPU library.
 """
 import os
+import re
 
 import pytest
 
@@ -140,3 +141,37 @@ def test_knn_values(one_chip, tiles):
         dn, g, idx, block=block, k_valid=K),
         ((N_KNN, kp), jnp.float32), ((N_KNN, kp, kp), jnp.float32),
         ((N_KNN, kp), jnp.int32))
+
+
+def test_kernels_keep_their_trace_names(one_chip):
+    """Each kernel's custom call is named by its ``pallas_call(name=...)``,
+    whatever the jitted wrapper around it is called: the device trace and
+    the benchmark's work models find kernels by these names."""
+    n, d, blk = 512, 128, 128
+    D, W = ((n, n), jnp.float32), ((n, n), jnp.float32)
+    X = ((n, d), jnp.float32)
+    fused = dict(metric="euclidean", n_valid=n - 5, block=blk, block_z=blk)
+    cases = {
+        "focus_tri_pallas": (lambda D: focus_tri_pallas(
+            D, block=blk, block_z=blk), D),
+        "cohesion_tri_pallas": (lambda D, W: cohesion_tri_pallas(
+            D, W, block=blk, block_z=blk), D, W),
+        "focus_pallas": (lambda D: focus_pallas(
+            D, block_xy=blk, block_z=blk), D),
+        "cohesion_pallas": (lambda D, W: cohesion_pallas(
+            D, W, block_x=blk, block_y=blk, block_z=blk), D, W),
+        "focus_fused_pallas": (lambda X: focus_fused_pallas(X, **fused), X),
+        "cohesion_fused_pallas": (lambda X, W: cohesion_fused_pallas(
+            X, W, **fused), X, W),
+        "topk_pallas": (lambda X: topk_pallas(
+            X, k=K, metric="euclidean", n_valid=n - 3, block=blk,
+            block_z=blk), X),
+        "knn_values_pallas": (lambda dn, g, idx: knn_values_pallas(
+            dn, g, idx, block=8, k_valid=K), ((n, 128), jnp.float32),
+            ((n, 128, 128), jnp.float32), ((n, 128), jnp.int32)),
+    }
+    for name, (fn, *shapes) in cases.items():
+        text = _compile(one_chip, fn, *shapes).as_text()
+        calls = re.findall(r"%([\w.-]+) = [^\n]*tpu_custom_call", text)
+        assert calls and all(re.fullmatch(rf"{name}\.\d+", c)
+                             for c in calls), (name, calls)
