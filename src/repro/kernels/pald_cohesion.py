@@ -43,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.weights import DEFAULT_TIES, resolve_weight
 
-from .tile_loops import col, cohesion_tile
+from .tile_loops import cohesion_tile, cols
 
 __all__ = ["cohesion_pallas"]
 
@@ -74,7 +74,8 @@ def _cohesion_kernel_xw(dxz_ref, dyz_ref, dxy_ref, w_ref, xw_ref, c_ref,
     wt_ref[...] = w_ref[...].T
     xwt_ref[...] = xw_ref[...].T        # 1.0 where global x index > global y
     c_ref[...] += cohesion_tile(dxz_ref[...], dyz_ref, dyx_ref, wt_ref, ties,
-                                lambda y: col(xwt_ref, y) > 0.5)
+                                lambda y0, g: [c > 0.5 for c in
+                                               cols(xwt_ref, y0, g)])
 
 
 def _cohesion_kernel_iota(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, dyx_ref,
@@ -98,7 +99,8 @@ def _cohesion_kernel_iota(dxz_ref, dyz_ref, dxy_ref, w_ref, c_ref, dyx_ref,
         jnp.int32, (block_x, 1), 0)                             # (bx, 1)
     ybase = col_off + k * block_y
     c_ref[...] += cohesion_tile(dxz_ref[...], dyz_ref, dyx_ref, wt_ref, ties,
-                                lambda y: xg > ybase + y)
+                                lambda y0, g: [xg > ybase + y0 + j
+                                               for j in range(g)])
 
 
 @functools.partial(jax.jit, static_argnames=("block_x", "block_z", "block_y",

@@ -40,7 +40,7 @@ Accumulation layout (grid = (nz, npairs), pairs innermost, x-major order):
 
 Every y-indexed read in the tile loop is a row read: D[X, Y] and W[X, Y]
 are transposed into (b, b) scratch once per step, and the y-role rows are
-built in a (b, bz) scratch (``tile_loops``).
+built in a (b, bz) scratch, eight y at a time (``tile_loops``).
 
 Diagonal blocks (xb == yb) apply the dense one-sided x-role over the full
 (block, block) pair square — that already covers both orders of every
@@ -62,7 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.weights import DEFAULT_TIES, resolve_weight, support_weight
 
-from .tile_loops import col
+from .tile_loops import cols, sum_rows, y_loop
 
 __all__ = ["cohesion_tri_pallas"]
 
@@ -88,27 +88,29 @@ def _cohesion_tri_kernel(xs_ref, ys_ref, dxz_ref, dyz_ref, dxy_ref, w_ref,
     b = dyx_ref.shape[0]
     is_diag = xb == yb
 
-    def body(y, acc_x):
-        row = dyz_ref[pl.ds(y, 1), :]   # (1, bz) d_yz
-        thr = col(dyx_ref, y)           # (b, 1)  d_xy
-        wy = col(wt_ref, y)             # (b, 1)
-        xw = yw = None
-        if ties.needs_index_tiebreak:
-            # global-index tiebreak from the prefetched block coordinates; on
-            # diagonal blocks the one-sided x-role visits both orders of every
-            # in-block pair, so xw alone implements the mode there
-            xg = xb * b + jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
-            yg = yb * b + y
-            xw, yw = xg > yg, yg > xg
-        gx = support_weight(dxz, row, thr, ties, xw)            # (b, bz)
-        acc_x = acc_x + gx * wy
-        # y-role: one output row, reduced over the x axis
-        gy = support_weight(row, dxz, thr, ties, yw)            # (b, bz)
-        ry_ref[pl.ds(y, 1), :] = jnp.sum(gy * wy, axis=0, keepdims=True)
+    def step(y0, g, acc_x):
+        ry = []
+        for j, (thr, wy) in enumerate(zip(cols(dyx_ref, y0, g),    # (b, 1) d_xy
+                                          cols(wt_ref, y0, g))):   # (b, 1) w
+            y = y0 + j
+            row = dyz_ref[pl.ds(y, 1), :]   # (1, bz) d_yz
+            xw = yw = None
+            if ties.needs_index_tiebreak:
+                # global-index tiebreak from the prefetched block coordinates;
+                # on diagonal blocks the one-sided x-role visits both orders of
+                # every in-block pair, so xw alone implements the mode there
+                xg = xb * b + jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+                yg = yb * b + y
+                xw, yw = xg > yg, yg > xg
+            gx = support_weight(dxz, row, thr, ties, xw)            # (b, bz)
+            acc_x = acc_x + gx * wy
+            # y-role: one output row, reduced over the x axis
+            gy = support_weight(row, dxz, thr, ties, yw)            # (b, bz)
+            ry.append(sum_rows(gy * wy))
+        ry_ref[pl.ds(y0, g), :] = jnp.concatenate(ry, axis=0)
         return acc_x
 
-    cx_ref[...] += jax.lax.fori_loop(0, b, body,
-                                     jnp.zeros(dxz.shape, jnp.float32))
+    cx_ref[...] += y_loop(b, step, jnp.zeros(dxz.shape, jnp.float32))
 
     @pl.when(jnp.logical_not(is_diag))
     def _update_cy():

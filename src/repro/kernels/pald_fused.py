@@ -127,7 +127,8 @@ def _cohesion_fused_kernel(xi_ref, xj_ref, xk_ref, w_ref, c_ref, dyz_ref,
     if ties.needs_index_tiebreak:
         # the grid owns both offsets, so the index tiebreak is an iota
         xg = xoff + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-        own_wins = lambda y: xg > yoff + y  # noqa: E731
+        own_wins = lambda y0, g: [xg > yoff + y0 + j  # noqa: E731
+                                  for j in range(g)]
 
     # the tile body of pald_cohesion._cohesion_kernel
     c_ref[...] += cohesion_tile(dxz, dyz_ref, dyx_ref, wt_ref, ties, own_wins)
