@@ -15,6 +15,9 @@ import jax.numpy as jnp
 from bench import data, reference as R
 from bench.job import Job, components, rel_err
 
+PROGRAM = ("repro.core.pald", "cohesion")
+RESULT = "dense"
+
 
 class DenseCohesion(Job):
     has_post = True

@@ -17,6 +17,9 @@ import jax.numpy as jnp
 from bench import data, reference as R
 from bench.job import Job, rel_err
 
+PROGRAM = ("repro.core.pald", "from_features")
+RESULT = "dense"
+
 
 _row_sums = jax.jit(lambda C: jnp.sum(C, axis=1))
 

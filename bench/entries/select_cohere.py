@@ -18,6 +18,9 @@ import jax.numpy as jnp
 from bench import data, reference as R
 from bench.job import Job, knn_arrays, knn_control_kept, knn_numbers
 
+PROGRAM = ("repro.kernels.ops", "select_cohere")
+RESULT = "knn"
+
 
 class SelectCohere(Job):
     has_post = True

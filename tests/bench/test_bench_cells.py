@@ -5,10 +5,9 @@ import jax
 import pytest
 
 from bench import control, discover, run
+from tiny import tiny_n
 
 MAN = discover.manifest()
-# tiny sizes: the Pallas kernels run in interpret mode here
-TINY = {"grqc-apsp": 160, "sift-128": 512}
 SEED = 2**33 + 17
 
 
@@ -19,7 +18,7 @@ def _devices(cell):
 @pytest.mark.parametrize("cell", MAN["workloads"], ids=lambda c: c["name"])
 def test_program_passes_every_limit(cell):
     r = run.run_cell(MAN, cell, SEED, 0.2, False, _devices(cell),
-                     n=TINY[cell["config"]], log=lambda s: None)
+                     n=tiny_n(MAN, cell), log=lambda s: None)
     assert r["attempted"] >= 1 and r["failed"] == 0
     assert r["correct"], r["checks"]
     assert list(r)[-1] == "checks"
@@ -30,7 +29,7 @@ def test_program_passes_every_limit(cell):
 @pytest.mark.parametrize("cell", MAN["workloads"], ids=lambda c: c["name"])
 def test_control_fails_a_limit(cell):
     rows = control.readings(MAN, cell, SEED + 1, True, _devices(cell),
-                            n=TINY[cell["config"]])
+                            n=tiny_n(MAN, cell))
     limits = discover.traffic(cell["traffic"])["limits"]
     assert all(rows["program"][k] <= v for k, v in limits.items())
     assert any(rows["control"][k] > v for k, v in limits.items())

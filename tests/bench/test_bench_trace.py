@@ -9,6 +9,8 @@ from bench import discover, trace as tr
 from bench.run import MetricContext, ROOT
 
 FIXTURE = Path(__file__).parent / "fixtures" / "sift65k-knn-sparse.xplane.pb"
+# the four-chip k-NN cell at n = 4,096, recorded by fixtures/record_mesh.py
+MESH = Path(__file__).parent / "fixtures" / "sift262k-knn-mesh4-4096.xplane.pb"
 LABELS = {"job.call", "job.communities", tr.BETWEEN}
 
 
@@ -128,3 +130,62 @@ def test_metrics_read_from_the_fixture(red):
     rows = ctx.kernel_rows()
     assert {r["kernel"] for r in rows} == {"topk", "knn_values"}
     assert all(r["bound"] in ("mxu", "vpu", "hbm") for r in rows)
+
+
+def test_collective_ms_on_hand_made_intervals():
+    red = tr.Reduced(
+        window=(0.0, 10.0),
+        ops={0: [("all-gather-start", 1.0, 1.5, "collective"),
+                 ("all-gather-done", 2.0, 3.0, "collective"),
+                 ("fusion", 3.0, 6.0, "xla")],
+             1: [("collective-permute-start", 1.0, 2.0, "collective"),
+                 ("collective-permute-done", 4.0, 4.5, "collective"),
+                 ("topk_pallas.1", 5.0, 9.0, "kernel")]},
+        spans=[("job.call", 0.5, 5.0), ("job.call", 5.0, 9.5)])
+    dev = SimpleNamespace(device_kind="TPU v5 lite")
+    ctx = MetricContext(red, 2, {}, [dev, dev], ROOT)
+    read = discover.module("metrics", "mesh.collective_ms").read
+    # (1.5 + 1.5) s over 2 devices, over 2 jobs
+    assert read(ctx) == pytest.approx(1e3 * 3.0 / 2 / 2)
+    red.ops = {d: [o for o in ops if o[3] != "collective"]
+               for d, ops in red.ops.items()}
+    assert read(ctx) is None
+
+
+def test_xla_ms_counts_a_loop_through_its_body():
+    red = tr.Reduced(
+        window=(0.0, 10.0),
+        ops={0: [("while.1", 1.0, 5.0, "xla"), ("top_k.2", 1.0, 3.0, "xla"),
+                 ("fusion.3", 3.5, 4.5, "xla"), ("all-gather", 5.0, 6.0,
+                                                  "collective"),
+                 ("k.1", 6.0, 8.0, "kernel"), ("custom-call", 6.0, 6.0,
+                                               "xla")]},
+        spans=[("job.call", 0.5, 9.0)])
+    dev = SimpleNamespace(device_kind="TPU v5 lite")
+    ctx = MetricContext(red, 1, {}, [dev], ROOT)
+    # the loop's 4 s are its body's 2 + 1, not 4 + 3
+    assert discover.module("metrics", "pipeline.xla_ms").read(ctx) == \
+        pytest.approx(3e3)
+
+
+def test_mesh_fixture_metrics_on_four_devices():
+    assert MESH.stat().st_size < 1 << 20
+    red = tr.reduce(MESH)
+    assert sorted(red.ops) == [0, 1, 2, 3]
+    assert not red.op_seconds("kernel") and red.op_seconds("collective")
+    man = discover.manifest()
+    cell = discover.workload(man, "sift262k-knn-mesh4")
+    jobs = len(red.spans_named("job.call"))
+    dev = SimpleNamespace(device_kind="TPU v5 lite")
+    ctx = MetricContext(red, jobs, dict(n=4096, d=128, k=32, p=4),
+                        [dev] * 4, ROOT)
+    got = {m["name"]: discover.module("metrics", m["name"]).read(ctx)
+           for m in discover.per_layer(man, cell)}
+    assert set(got) == {"device.idle_frac", "pipeline.xla_ms",
+                        "mesh.collective_ms"}
+    assert 0 < got["device.idle_frac"] < 1 and got["mesh.collective_ms"] > 0
+    # the selection loops' bodies are counted once: XLA and collective
+    # time add up to the busy time
+    busy_ms = 1e3 * red.mean_busy_s() / jobs
+    assert got["pipeline.xla_ms"] + got["mesh.collective_ms"] == \
+        pytest.approx(busy_ms, rel=0.02)
