@@ -65,6 +65,7 @@ __all__ = [
     "pald_fused",
     "pald_knn",
     "knn_values",
+    "knn_cube_layout",
     "topk_select",
     "select_cohere",
     "focus",
@@ -700,14 +701,71 @@ def pald_tri(
 # The jnp fallback streams the gathered (block, k, k) neighbor tiles chunk
 # by chunk (O(block * k^2) live); the Pallas path stages the full gathered
 # cube in HBM (O(n * k^2)) and lets the kernel iterate (block, k) tiles.
+# The cube is one program that walks the padded rows in chunks, so the
+# (rows, k, d) feature gather each chunk stages stays within
+# ``_CUBE_STAGE_BYTES`` whatever d is.
 # --------------------------------------------------------------------------
 from repro.core import knn as _knn  # noqa: E402
+
+_CUBE_STAGE_BYTES = 1 << 30
 
 
 def _gather_tiles(x, idxc, kind: str, metric: str):
     if kind == "distance":
         return _knn.gather_tile_from_distances(x, idxc)
     return _knn.gather_tile_from_features(x, idxc, metric)
+
+
+def knn_cube_layout(n: int, k: int, d: int | None = None, *,
+                    block: int | str = "auto", impl: str | None = None,
+                    ties=DEFAULT_TIES) -> dict:
+    """How ``knn_values`` stages the Pallas path's neighbor cube.
+
+    For an (n, k) graph over (n, d) features, or over a distance matrix
+    (``d=None``): the knn row ``block`` (``"auto"`` resolves as
+    ``knn_values`` does), the ``padded_rows`` m, the ``rows`` of each
+    chunk (whole blocks, the chunks as even as whole blocks allow), the
+    number of ``chunks``, and ``staged_bytes``, the f32 bytes one chunk
+    gathers: (rows, k, d) features, or its own (rows, k, k) tile from a
+    distance matrix.  One block a chunk where a single block already
+    exceeds the budget."""
+    if block == "auto":
+        block, _ = _tuner.resolve_blocks(n, "pald_knn", impl=impl or
+                                         _default_impl(),
+                                         ties=resolve_weight(ties), k=k)
+    block = max(min(int(block), n), 1)
+    m = -(-n // block) * block
+    per_row = 4 * k * (k if d is None else d)
+    blocks = m // block
+    fit = max(_CUBE_STAGE_BYTES // (block * per_row), 1)   # blocks a chunk
+    chunks = -(-blocks // fit)
+    rows = -(-blocks // chunks) * block
+    return dict(block=block, padded_rows=m, rows=rows,
+                chunks=-(-m // rows), staged_bytes=rows * per_row)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("kind", "metric", "rows", "kp"))
+def _neighbor_cube(x, dn_p, idx_p, *, kind: str, metric: str, rows: int,
+                   kp: int):
+    """The Pallas path's operands in one program: the (m, kp) lane-padded
+    neighbor distances and indices, and the (m, kp, kp) neighbor cube.
+
+    Chunk i gathers rows [r0, r0 + rows) with r0 = min(i * rows, m - rows):
+    the last chunk ends at m and recomputes the rows it shares with the one
+    before it, to the same bits.  Each chunk is the tile ``_gather_tiles``
+    gives for its rows, written into the zeroed lane-padded cube."""
+    m, k = idx_p.shape
+
+    def chunk(i, g):
+        r0 = jnp.minimum(i * rows, m - rows)
+        idxc = jax.lax.dynamic_slice_in_dim(idx_p, r0, rows, 0)
+        return jax.lax.dynamic_update_slice(
+            g, _gather_tiles(x, idxc, kind, metric), (r0, 0, 0))
+
+    g = jax.lax.fori_loop(0, -(-m // rows), chunk,
+                          jnp.zeros((m, kp, kp), jnp.float32))
+    return _pad2(dn_p, m, kp, jnp.inf), _pad2(idx_p, m, kp, 0), g
 
 
 @functools.partial(jax.jit,
@@ -769,11 +827,9 @@ def knn_values(
     n, k = graph.indices.shape
     if k == 0:  # n == 1 (or an explicit empty graph): no pairs, no support
         return jnp.zeros((n, 1), jnp.float32)
-    if block == "auto":
-        block, _ = _tuner.resolve_blocks(n, "pald_knn", impl=impl, ties=ties,
-                                         k=k)
-    block = max(min(int(block), n), 1)
-    m = -(-n // block) * block
+    cube = knn_cube_layout(n, k, x.shape[1] if kind == "features" else None,
+                           block=block, impl=impl, ties=ties)
+    block, m = cube["block"], cube["padded_rows"]
     with TraceAnnotation("pipeline.pad"):
         dn_p = _pad2(graph.distances.astype(jnp.float32), m, k, jnp.inf)
         idx_p = _pad2(graph.indices, m, k, 0)
@@ -785,17 +841,15 @@ def knn_values(
     from .pald_knn import knn_values_pallas
 
     with TraceAnnotation("pipeline.gather_cube"):
-        g = _gather_tiles(x, idx_p, kind, metric)      # (m, k, k), real k
+        # lane-pad the neighbor axis around the real-k gather (a pre-pad
+        # gather would stage and recompute a (kp/k)^2-times-larger cube):
+        # +inf pair distances, index 0, zero gathered distances — the
+        # kernel masks every padded column out of the focus count and pair
+        # weights via k_valid
         kp = k if impl == "interpret" else -(-k // 128) * 128
-        if kp != k:
-            # lane-pad the neighbor axis AFTER gathering (a pre-pad gather
-            # would stage and recompute a (kp/k)^2-times-larger cube): +inf
-            # pair distances, index 0, zero gathered distances — the kernel
-            # masks every padded column out of the focus count and pair
-            # weights via k_valid
-            dn_p = _pad2(dn_p, m, kp, jnp.inf)
-            idx_p = _pad2(idx_p, m, kp, 0)
-            g = jnp.pad(g, ((0, 0), (0, kp - k), (0, kp - k)))
+        dn_p, idx_p, g = _neighbor_cube(x, dn_p, idx_p, kind=kind,
+                                        metric=metric, rows=cube["rows"],
+                                        kp=kp)
     with TraceAnnotation("kernel.knn_values"):
         vals = knn_values_pallas(dn_p, g, idx_p, block=block, k_valid=k,
                                  ties=ties, interpret=impl == "interpret")

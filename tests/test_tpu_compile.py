@@ -29,6 +29,7 @@ HBM_BYTES = 16 << 30          # one v5e chip
 N_TRI = 5242                  # chip_smoke dense-tri (ca-GrQc's node count)
 N_DENSE = 4096                # dense and fused kernels, d = 128
 N_KNN, D, K = 65536, 128, 32  # chip_smoke knn-sparse
+D_WIDE = 960                  # the GIST-960 shape, at the same n and k
 
 
 @pytest.fixture(scope="module")
@@ -131,6 +132,35 @@ def test_topk_selection(one_chip, tiles):
     _compile(one_chip, lambda X: topk_pallas(
         X, k=K, metric="euclidean", n_valid=N_KNN - 3, block=block,
         block_z=tile), ((N_KNN, D), jnp.float32))
+
+
+def test_topk_selection_wide(one_chip, tiles):
+    """The selection's (block, d) and (block_z, d) feature tiles at a
+    feature axis of 7.5 lane-widths, unpadded."""
+    block, tile = tiles(N_KNN, "pald_topk", d=D_WIDE, k=K)
+    _compile(one_chip, lambda X: topk_pallas(
+        X, k=K, metric="euclidean", n_valid=N_KNN, block=block,
+        block_z=tile), ((N_KNN, D_WIDE), jnp.float32))
+
+
+@pytest.mark.parametrize("d", (D, D_WIDE))
+def test_neighbor_cube(one_chip, tiles, d):
+    """The knn pipeline's neighbor cube is one program whose temporaries
+    (the chunk's staged (rows, k, d) gather) stay under 2 GiB at any d; the
+    lane-padded (m, 128, 128) cube is its output."""
+    from repro.kernels import ops
+
+    block, _ = tiles(N_KNN, "pald_knn", k=K)
+    lay = ops.knn_cube_layout(N_KNN, K, d, block=block)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in (((N_KNN, d), jnp.float32), ((N_KNN, K), jnp.float32),
+                          ((N_KNN, K), jnp.int32))]
+    compiled = jax.jit(lambda x, dn, idx: ops._neighbor_cube(
+        x, dn, idx, kind="features", metric="euclidean", rows=lay["rows"],
+        kp=128)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 << 30, mem.temp_size_in_bytes
+    assert mem.output_size_in_bytes >= N_KNN * 128 * 128 * 4
 
 
 def test_knn_values(one_chip, tiles):
